@@ -85,6 +85,33 @@ def constrain_batch(x):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+def per_shard_attention(attn):
+    """Run ``attn(q, k, v)`` on (B, S, H, D) arrays shard by shard.
+
+    GSPMD cannot partition a Mosaic (Pallas TPU) kernel, so under a
+    multi-device scope the kernel runs inside a ``shard_map``: batch over
+    the data-parallel axes and heads over ``model`` where they divide,
+    replicated where they do not.  Attention is independent per sequence
+    and per kv-head group, so the shards need no collective."""
+    mesh = getattr(_ACT_CTX, "mesh", None)
+    if mesh is None or mesh.size == 1:
+        return attn
+
+    def sharded(q, k, v):
+        dp = dp_axes(mesh)
+        bax = dp if dp and q.shape[0] % _axis_size(mesh, dp) == 0 else None
+        hax = ("model" if "model" in mesh.axis_names
+               and k.shape[2] % mesh.shape["model"] == 0 else None)
+        spec = P(bax, None, hax, None)
+        manual = set(mesh.axis_names) - getattr(_ACT_CTX, "skip_axes",
+                                                frozenset())
+        return jax.shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, axis_names=manual,
+                             check_vma=False)(q, k, v)
+
+    return sharded
+
+
 def constrain_logits(x):
     """Logits: batch over the DP axes AND vocab over the model axis.
     (Batch-only pinning replicates the vocab dim — a 64 GiB/device fp32

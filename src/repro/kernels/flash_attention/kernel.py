@@ -28,15 +28,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 exposes CompilerParams as TPUCompilerParams; alias for compat.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                scale: float, causal: bool, window: int | None,
-               q_offset: int, bq: int, bk: int, nk: int):
+               q_offset: int, bq: int, bk: int, nk: int, k_limit: int | None):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -62,6 +59,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         qpos = q_start + (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % bq)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = jnp.ones(s.shape, dtype=bool)
+        if k_limit is not None:  # keys padded up to a block multiple
+            mask &= kpos < k_limit
         if causal:
             mask &= kpos <= qpos
         if window is not None:
@@ -99,18 +98,26 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            scale: float | None = None,
                            block_q: int = 128, block_k: int = 128,
                            interpret: bool = False):
-    """q: (B, Sq, Hq, D); k/v: (B, Sk, Hkv, D) -> (B, Sq, Hq, D)."""
-    B, Sq, Hq, D = q.shape
-    _, Sk, Hkv, _ = k.shape
+    """q: (B, Sq, Hq, D); k/v: (B, Sk, Hkv, D) -> (B, Sq, Hq, D).
+
+    Sequences are padded up to block multiples: padded queries are sliced
+    off the output and padded keys are masked in the kernel."""
+    B, Sq0, Hq, D = q.shape
+    _, Sk0, Hkv, _ = k.shape
     assert Hq % Hkv == 0
     G = Hq // Hkv
     if scale is None:
         scale = D ** -0.5
     if q_offset is None:
-        q_offset = Sk - Sq
-    bq = min(block_q, Sq)
-    bk = min(block_k, Sk)
-    assert Sq % bq == 0 and Sk % bk == 0, "pad sequence to block multiples"
+        q_offset = Sk0 - Sq0
+    bq = min(block_q, Sq0)
+    bk = min(block_k, Sk0)
+    pad_seq = lambda x, n: jnp.pad(x, ((0, 0), (0, n), (0, 0), (0, 0)))
+    q = pad_seq(q, (-Sq0) % bq)
+    k = pad_seq(k, (-Sk0) % bk)
+    v = pad_seq(v, (-Sk0) % bk)
+    Sq, Sk = q.shape[1], k.shape[1]
+    k_limit = Sk0 if Sk != Sk0 else None
     nq, nk = Sq // bq, Sk // bk
     # Reorder to (B*Hkv, ...) with the G q-heads of each kv head contiguous.
     qr = (q.transpose(0, 2, 1, 3)                        # (B, Hq, Sq, D)
@@ -129,7 +136,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
 
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal, window=window,
-        q_offset=q_offset, bq=bq, bk=bk, nk=nk)
+        q_offset=q_offset, bq=bq, bk=bk, nk=nk, k_limit=k_limit)
 
     # q block gathers the G head-slices for this q tile: we expose q as
     # (B*Hkv, nq, G*bq, D) by reshaping rows so that tile qi holds rows
@@ -154,7 +161,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
             pltpu.VMEM((G * bq, 1), jnp.float32),   # running max m
             pltpu.VMEM((G * bq, 1), jnp.float32),   # running sum l
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)
@@ -164,4 +171,4 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
               .transpose(0, 1, 3, 2, 4, 5)               # (B, Hkv, G, nq, bq, D)
               .reshape(B, Hq, Sq, D)
               .transpose(0, 2, 1, 3))
-    return out
+    return out[:, :Sq0]

@@ -1,7 +1,9 @@
 """Dispatching wrapper for flash attention.
 
 ``flash_attention`` picks the implementation:
-  * ``pallas``      — the Mosaic TPU kernel (kernel.py), on TPU backends;
+  * ``pallas``      — the Mosaic TPU kernel (kernel.py), on TPU backends.
+    It has no backward kernel: its VJP is that of ``xla_chunked`` on the
+    same inputs (an XLA blockwise backward that recomputes the forward);
   * ``xla_chunked`` — a pure-jnp blockwise online-softmax implementation
     (lax.scan over KV blocks) with the same memory behaviour: activations
     are O(S * block) instead of O(S^2).  Used on CPU (incl. the multi-pod
@@ -18,6 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.distributed.sharding import per_shard_attention
 from repro.kernels.flash_attention.ref import attention_ref
 
 NEG_INF = -1e30
@@ -99,6 +102,29 @@ def flash_attention_xla(q, k, v, *, causal=True, window=None, q_offset=None,
     return out.astype(q.dtype)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _pallas_xla_vjp(q, k, v, causal, window, q_offset, scale, interpret):
+    from repro.kernels.flash_attention.kernel import flash_attention_pallas
+    return flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                  q_offset=q_offset, scale=scale,
+                                  interpret=interpret)
+
+
+def _pallas_xla_vjp_fwd(q, k, v, causal, window, q_offset, scale, interpret):
+    out = _pallas_xla_vjp(q, k, v, causal, window, q_offset, scale, interpret)
+    return out, (q, k, v)
+
+
+def _pallas_xla_vjp_bwd(causal, window, q_offset, scale, interpret, res, g):
+    _, vjp = jax.vjp(functools.partial(
+        flash_attention_xla, causal=causal, window=window, q_offset=q_offset,
+        scale=scale), *res)
+    return vjp(g)
+
+
+_pallas_xla_vjp.defvjp(_pallas_xla_vjp_fwd, _pallas_xla_vjp_bwd)
+
+
 def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     q_offset: int | None = None, scale: float | None = None,
                     impl: str | None = None, block_q: int = 512,
@@ -107,11 +133,10 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "xla_chunked"
     if impl == "pallas":
-        from repro.kernels.flash_attention.kernel import flash_attention_pallas
-        return flash_attention_pallas(
-            q, k, v, causal=causal, window=window, q_offset=q_offset,
-            scale=scale, block_q=min(128, q.shape[1]),
-            block_k=min(128, k.shape[1]), interpret=interpret)
+        kernel = functools.partial(_pallas_xla_vjp, causal=causal,
+                                   window=window, q_offset=q_offset,
+                                   scale=scale, interpret=interpret)
+        return per_shard_attention(kernel)(q, k, v)
     if impl == "xla_chunked":
         return flash_attention_xla(q, k, v, causal=causal, window=window,
                                    q_offset=q_offset, scale=scale,

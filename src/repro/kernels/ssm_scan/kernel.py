@@ -28,9 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 exposes CompilerParams as TPUCompilerParams; alias for compat.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 CLAMP = 30.0
 
 
@@ -46,25 +43,39 @@ def _gla_kernel(q_ref, k_ref, v_ref, w_ref, o_ref, sfin_ref, state_ref, *,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)            # (C, V)
     w = jnp.clip(w_ref[0].astype(jnp.float32), -CLAMP, 0.0)
-    a = jnp.cumsum(w, axis=0)                   # (C, K) running log decay
+    ii = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    causal = jj <= ii
+    # Running log decay a = cumsum(w) as a matmul by a lower-triangular
+    # ones matrix: Mosaic has no cumsum.  HIGHEST keeps the f32 sum exact
+    # enough for exp(-a) (a bf16 pass would round w at 2^-8).
+    tri = jnp.where(causal, 1.0, 0.0).astype(jnp.float32)
+    a = jax.lax.dot_general(tri, w, (((1,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)  # (C, K)
     ea = jnp.exp(a)
     q_t = q * ea                                # q~
     # fp32 exponent guard (see ops.gla_scan_xla): saturate exp(-a) at e^60.
     k_t = k * jnp.exp(jnp.minimum(-a, 60.0))    # k~
     s = jax.lax.dot_general(q_t, k_t, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (C, C)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    s = jnp.where(jj <= ii, s, 0.0)
+    s = jnp.where(causal, s, 0.0)
     intra = jax.lax.dot_general(s, v, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (C, V)
     cross = jax.lax.dot_general(q_t, state_ref[...], (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
     o_ref[0] = (intra + cross).astype(o_ref.dtype)
-    # State update: S' = diag(exp(a_last)) S + (k~ * exp(a_last))^T v
-    ea_last = ea[C - 1]                          # (K,)
-    k_fin = k_t * ea_last[None, :]
-    state_ref[...] = (state_ref[...] * ea_last[:, None]
+    # State update: S' = diag(exp(a_last)) S + (k~ * exp(a_last))^T v.
+    # exp(a_last) is needed as a row (scaling k~'s columns) and as a column
+    # (scaling S's rows); the column is the chunk sum of w, taken as a
+    # matmul so no lane-to-sublane transpose is needed.
+    ea_last_row = ea[C - 1:C, :]                 # (1, K)
+    a_last_col = jax.lax.dot_general(
+        w, jnp.ones((C, 1), jnp.float32), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)      # (K, 1)
+    k_fin = k_t * ea_last_row
+    state_ref[...] = (state_ref[...] * jnp.exp(a_last_col)
                       + jax.lax.dot_general(k_fin, v, (((0,), (0,)), ((), ())),
                                             preferred_element_type=jnp.float32))
 
@@ -105,7 +116,7 @@ def gla_scan_pallas(q, k, v, w, chunk: int = 128, interpret: bool = False):
             jax.ShapeDtypeStruct((BH, K, V), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr, wr)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without hardware: for each cell
@@ -11,8 +8,9 @@ lowered with explicit in/out shardings onto the production mesh
 (parsed from the compiled HLO) are written to ``results/dryrun/*.json`` —
 the inputs to the §Roofline analysis.
 
-NOTE: the two lines above MUST run before any other import — jax locks the
-device count at first initialization.
+Run as a script, it asks XLA for 512 virtual CPU devices before JAX first
+initializes its backend (the device count is fixed from then on); importing
+the module changes nothing.
 
 Usage:
   python -m repro.launch.dryrun --arch tinyllama_1p1b --shape train_4k
@@ -21,6 +19,7 @@ Usage:
 
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -293,4 +292,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

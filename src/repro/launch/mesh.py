@@ -16,21 +16,33 @@ chips).  Axis meanings:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    In jax 0.9 mesh axes default to ``Explicit``, which rejects the
+    ``P.UNCONSTRAINED`` entries of the activation pins in
+    ``repro.distributed.sharding``; the models are written for GSPMD's
+    automatic propagation."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(devices: int | None = None):
     """Small mesh over whatever devices exist (tests / smoke runs)."""
     n = devices or len(jax.devices())
     if n == 1:
-        return jax.make_mesh((1, 1), ("data", "model"))
+        return make_mesh((1, 1), ("data", "model"))
     d = max(1, n // 2)
-    return jax.make_mesh((d, n // d), ("data", "model"))
+    return make_mesh((d, n // d), ("data", "model"))
 
 
 # v5e hardware constants (roofline denominators).

@@ -1,8 +1,9 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> ...``
 
-Stands up the continuous-batching scheduler for an architecture (reduced
-config on CPU) and serves synthetic requests, reporting decode throughput
-and the DDS KV-paging statistics when --paged is set.
+Stands up the continuous-batching scheduler for an architecture (the
+reduced same-family config unless ``--no-reduced``) and serves synthetic
+requests, reporting decode throughput on the device it ran on and the DDS
+KV-paging statistics when --paged is set.
 """
 
 from __future__ import annotations
@@ -14,6 +15,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, reduced_config
+from repro.launch.device import device_report, enable_compile_cache
 from repro.models.registry import build_model
 from repro.serve.engine import BatchScheduler, PagedKVEngine, Request
 from repro.storage.pagestore import PageStore
@@ -26,10 +28,15 @@ def main() -> None:
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--cache-len", type=int, default=128)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced same-family config (--no-reduced: "
+                         "published widths)")
     ap.add_argument("--paged", action="store_true",
                     help="demonstrate DDS KV-block paging")
     args = ap.parse_args()
+    cache_dir = enable_compile_cache()
+    dev = device_report()
 
     cfg = reduced_config(get_config(args.arch)) if args.reduced else \
         get_config(args.arch)
@@ -50,7 +57,8 @@ def main() -> None:
     toks = args.requests * args.max_new
     print(f"arch={cfg.name}: {args.requests} requests x {args.max_new} "
           f"tokens over {args.slots} slots: {steps} steps, "
-          f"{toks / dt:,.0f} tok/s (CPU)")
+          f"{toks / dt:,.0f} tok/s on {dev['count']} x {dev['kind']} "
+          f"({dev['platform']}; compile cache {cache_dir})")
 
     if args.paged:
         store = PageStore(page_size=4096, num_pages=256)

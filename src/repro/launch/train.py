@@ -12,11 +12,9 @@ import argparse
 import dataclasses
 import time
 
-import jax
-
 from repro.configs import ARCH_IDS, get_config, reduced_config
-from repro.core.dds_server import DDSStorageServer, ServerConfig
 from repro.data.pipeline import BatchSpec, TokenPipeline
+from repro.launch.device import device_report, enable_compile_cache
 from repro.models.registry import build_model
 from repro.storage.checkpoint import CheckpointManager
 from repro.train.loop import TrainConfig, Trainer
@@ -35,6 +33,7 @@ def main() -> None:
                     help="reduced same-family config (CPU-runnable)")
     ap.add_argument("--compress-pod-grads", action="store_true")
     args = ap.parse_args()
+    cache_dir = enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -42,17 +41,15 @@ def main() -> None:
     api = build_model(cfg)
     print(f"arch={cfg.name} family={cfg.family} "
           f"params~{cfg.param_count() / 1e9:.2f}B "
-          f"devices={len(jax.devices())}")
+          f"devices={device_report()} compile_cache={cache_dir}")
 
     pipeline = TokenPipeline(BatchSpec(args.batch, args.seq, cfg.vocab_size),
                              seed=0)
-    ckpt = CheckpointManager(
-        DDSStorageServer(ServerConfig(device_capacity=1 << 30)), keep=3)
     tcfg = TrainConfig(peak_lr=args.lr, warmup_steps=max(2, args.steps // 10),
                        total_steps=args.steps, microbatch=args.microbatch,
                        compress_pod_grads=args.compress_pod_grads)
-    trainer = Trainer(api, tcfg, pipeline, checkpoint_mgr=ckpt,
-                      ckpt_every=args.ckpt_every)
+    trainer = Trainer(api, tcfg, pipeline, ckpt_every=args.ckpt_every)
+    trainer.ckpt = CheckpointManager.sized_for(trainer.state_tree(), keep=3)
     if trainer.restore_latest():
         print(f"resumed at step {trainer.step}")
     t0 = time.time()

@@ -17,6 +17,7 @@ leave decode slots between steps.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any
@@ -61,29 +62,29 @@ def make_serve_fns(api: ModelAPI, mesh: Mesh, axes_tree,
         sh.param_specs(axes_tree, mesh, api.cfg, fsdp=True), pshapes, mesh)
     dp = sh.dp_axes(mesh)
     ns = lambda s: NamedSharding(mesh, s)
+    row_sh = ns(P(dp if shape.global_batch >= _ndp(mesh) else None, None))
+
+    def cache_sh(cache_like):
+        return sh.to_shardings(
+            sh.cache_specs(cache_like, mesh, api.cfg, shape), mesh)
 
     def decode_jit(cache_like):
-        cspecs = sh.cache_specs(cache_like, mesh, api.cfg, shape)
-        in_sh = (jax.tree_util.tree_map(ns, pspecs,
-                                        is_leaf=lambda x: isinstance(x, P)),
-                 jax.tree_util.tree_map(ns, cspecs,
-                                        is_leaf=lambda x: isinstance(x, P)),
-                 ns(P()),
-                 ns(P(dp if shape.global_batch >= _ndp(mesh) else None, None)))
-        out_sh = (ns(P(dp if shape.global_batch >= _ndp(mesh) else None,
-                       None)),
-                  jax.tree_util.tree_map(ns, cspecs,
-                                         is_leaf=lambda x: isinstance(x, P)))
-        return jax.jit(api.decode_step, in_shardings=in_sh,
-                       out_shardings=out_sh)
+        csh = cache_sh(cache_like)
+        return jax.jit(api.decode_step,
+                       in_shardings=(sh.to_shardings(pspecs, mesh), csh,
+                                     ns(P()), row_sh),
+                       out_shardings=(row_sh, csh))
 
-    def prefill_jit(batch_like):
+    def prefill_jit(batch_like, cache_len: int | None = None):
+        """The prefill's cache leaves with decode's cache shardings, so
+        decode takes it as it is; ``cache_len`` leaves room to decode."""
         bspecs = sh.batch_specs(mesh, shape, api.cfg)
         in_b = {k: ns(bspecs.get(k, P(dp, None))) for k in batch_like}
-        in_sh = (jax.tree_util.tree_map(ns, pspecs_prefill,
-                                        is_leaf=lambda x: isinstance(x, P)),
-                 in_b)
-        return jax.jit(api.prefill, in_shardings=in_sh)
+        in_sh = (sh.to_shardings(pspecs_prefill, mesh), in_b)
+        fn = functools.partial(api.prefill, cache_len=cache_len)
+        _, cache_like = jax.eval_shape(fn, pshapes, batch_like)
+        return jax.jit(fn, in_shardings=in_sh,
+                       out_shardings=(row_sh, cache_sh(cache_like)))
 
     return prefill_jit, decode_jit
 

@@ -29,7 +29,7 @@ import numpy as np
 
 import jax
 
-from repro.core.dds_server import DDSStorageServer
+from repro.core.dds_server import DDSStorageServer, ServerConfig
 
 
 def _leaf_paths(tree: Any) -> list[tuple[str, Any]]:
@@ -61,6 +61,21 @@ class CheckpointManager:
         self._async_thread: threading.Thread | None = None
         self._async_err: list[BaseException] = []
         self._lock = threading.Lock()
+
+    @classmethod
+    def sized_for(cls, tree: Any, keep: int = 3) -> "CheckpointManager":
+        """A manager on a new server whose device holds ``keep + 1``
+        checkpoints of trees shaped like ``tree`` (arrays or
+        ShapeDtypeStructs): a save lands before the oldest is collected."""
+        cfg = ServerConfig()
+        seg = cfg.segment_size
+        per_ckpt = seg                                 # the manifest
+        for leaf in jax.tree_util.tree_leaves(tree):
+            nbytes = int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+            per_ckpt += -(-nbytes // seg) * seg        # files own whole segments
+        reserved = (2 + cfg.journal_segments) * seg    # metadata + journal + spare
+        cfg = ServerConfig(device_capacity=(keep + 1) * per_ckpt + reserved)
+        return cls(DDSStorageServer(cfg), keep=keep)
 
     # -- save -------------------------------------------------------------------------
     def save(self, step: int, tree: Any) -> CheckpointInfo:

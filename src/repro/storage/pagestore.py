@@ -57,6 +57,7 @@ class PageStore:
                  config: ServerConfig | None = None):
         self.page_size = page_size
         self.payload_size = page_size - PAGE_HDR.size
+        self._filling: dict[int, tuple[int, int]] = {}  # page -> (lsn, next)
         api = OffloadAPI(self._off_pred, self._off_func,
                          cache=self._cache, invalidate=self._invalidate,
                          response_header=self._resp_header,
@@ -95,17 +96,29 @@ class PageStore:
         return ReadOp(item.file_id, item.offset, item.size)
 
     def _cache(self, op: WriteOp) -> list[tuple[object, object]]:
-        """cache-on-write: every aligned page fully covered by the write."""
+        """cache-on-write: every page this write completes.
+
+        The host library splits a write larger than its largest request into
+        consecutive pieces, so a big page may arrive in several.  Its first
+        piece carries the LSN header; the page is cached when the piece that
+        ends it lands right after the one before.  A write that does not
+        continue a page leaves it to the host (host-fresh)."""
         out = []
-        if op.offset % self.page_size != 0:
-            return out  # unaligned partial write: leave cache alone (host-fresh)
-        pos = 0
-        while pos + self.page_size <= len(op.data):
-            off = op.offset + pos
-            (lsn,) = PAGE_HDR.unpack_from(op.data, pos)
-            page_id = off // self.page_size
-            out.append((page_id, PageItem(op.file_id, off, self.page_size, lsn)))
-            pos += self.page_size
+        ps = self.page_size
+        pos, end = op.offset, op.offset + len(op.data)
+        while pos < end:
+            page_id, into = divmod(pos, ps)
+            stop = min(end, (page_id + 1) * ps)
+            fill = self._filling.pop(page_id, None)
+            if into == 0 and stop - pos >= PAGE_HDR.size:
+                fill = (PAGE_HDR.unpack_from(op.data, pos - op.offset)[0], pos)
+            if fill is not None and fill[1] == pos:
+                if stop == (page_id + 1) * ps:
+                    out.append((page_id, PageItem(op.file_id, page_id * ps,
+                                                  ps, fill[0])))
+                else:
+                    self._filling[page_id] = (fill[0], stop)
+            pos = stop
         return out
 
     def _invalidate(self, op: ReadOp) -> list[object]:

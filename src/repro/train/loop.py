@@ -33,19 +33,6 @@ from repro.optim.compression import (compress_tree, decompress_tree,
                                      init_compression)
 
 
-def _shard_map(f, *, mesh, axis_names, check_vma, in_specs, out_specs):
-    """jax.shard_map appeared in jax 0.5; fall back to the experimental API
-    (manual over ``axis_names`` only => the rest of the mesh goes in ``auto``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, axis_names=axis_names,
-                             check_vma=check_vma,
-                             in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map
-    auto = frozenset(mesh.axis_names) - set(axis_names)
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma, auto=auto)
-
-
 @dataclass
 class TrainConfig:
     peak_lr: float = 3e-4
@@ -189,7 +176,7 @@ def make_compressed_pod_train_fn(api: ModelAPI, tcfg: TrainConfig,
                        for k in batch}
         err_specs = jax.tree_util.tree_map(
             lambda _: jax.sharding.PartitionSpec("pod"), params)
-        fn = _shard_map(
+        fn = jax.shard_map(
             per_pod, mesh=mesh, axis_names={"pod"}, check_vma=False,
             in_specs=(pod_specs, err_specs, batch_specs),
             out_specs=(pod_specs, err_specs,
@@ -256,7 +243,7 @@ class Trainer:
 
     def __init__(self, api: ModelAPI, tcfg: TrainConfig, pipeline,
                  checkpoint_mgr=None, mesh: Mesh | None = None,
-                 ckpt_every: int = 100):
+                 ckpt_every: int = 100, key=None):
         self.api = api
         self.tcfg = tcfg
         self.pipeline = pipeline
@@ -264,7 +251,7 @@ class Trainer:
         self.ckpt_every = ckpt_every
         self.mesh = mesh
         self.params, self.opt, self.comp, self.axes = init_train_state(
-            api, tcfg)
+            api, tcfg, key)
         self.step = 0
         self.history: list[dict] = []
         self._step_fn = jax.jit(make_train_fn(api, tcfg))
@@ -275,14 +262,17 @@ class Trainer:
         latest = self.ckpt.latest_step()
         if latest is None:
             return False
-        tree = {"params": self.params, "mu": self.opt.mu, "nu": self.opt.nu}
-        back = self.ckpt.restore(latest, tree)
+        back = self.ckpt.restore(latest, self.state_tree())
         self.params = back["params"]
         self.opt = self.opt._replace(
             mu=back["mu"], nu=back["nu"],
             count=jnp.asarray(latest, jnp.int32))
         self.step = latest
         return True
+
+    def state_tree(self) -> dict:
+        """What a checkpoint holds: parameters and Adam's moments."""
+        return {"params": self.params, "mu": self.opt.mu, "nu": self.opt.nu}
 
     def run(self, steps: int) -> list[dict]:
         for _ in range(steps):
@@ -296,10 +286,7 @@ class Trainer:
             self.history.append(rec)
             self.step += 1
             if self.ckpt is not None and self.step % self.ckpt_every == 0:
-                self.ckpt.save_async(
-                    self.step,
-                    {"params": self.params, "mu": self.opt.mu,
-                     "nu": self.opt.nu})
+                self.ckpt.save_async(self.step, self.state_tree())
         if self.ckpt is not None:
             self.ckpt.wait_async()
         return self.history

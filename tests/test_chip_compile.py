@@ -1,0 +1,160 @@
+"""Compile the main path for a described TPU v5e chip, with no chip attached.
+
+The TPU compiler refuses what interpret mode accepts: unaligned slices,
+primitives Mosaic cannot lower, kernels over the VMEM budget, programs over
+the device's memory.  These tests run the compiler on the kernels and on the
+StarCoder2-7B decode and train steps at published widths, so such faults
+show up without chip time.  Nothing executes, so results are not checked
+here.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every pytest worker imports
+this file.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.paged_attention.kernel import paged_attention_pallas
+from repro.kernels.ssm_scan.kernel import gla_scan_pallas
+from repro.models import transformer as TF
+from repro.models.registry import build_model
+from repro.optim import AdamWState
+from repro.train.loop import TrainConfig, abstract_init, make_train_fn
+
+HBM_BYTES = 16 * 1024 ** 3          # one v5e chip
+SC2 = get_config("starcoder2_7b")    # Hq 36, Hkv 4, head dim 128
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def topo(no_compile_cache):
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def pallas_dispatch(monkeypatch):
+    """The dispatchers pick Pallas only when JAX's backend is a TPU; here
+    the backend is the CPU, so steer them for the compile."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _sds(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_fits_with_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, total
+
+
+def test_paged_attention_compiles_at_starcoder2_widths(one_chip):
+    B, pages_per_seq, page = 8, 32, 128
+    q = _sds(one_chip, (B, SC2.num_heads, SC2.hd))
+    pool = _sds(one_chip, (B * pages_per_seq, page, SC2.num_kv_heads, SC2.hd))
+    table = _sds(one_chip, (B, pages_per_seq), jnp.int32)
+    lens = _sds(one_chip, (B,), jnp.int32)
+    compiled = jax.jit(paged_attention_pallas).lower(
+        q, pool, pool, table, lens).compile()
+    _assert_fits_with_kernel(compiled)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention_fwd_and_grad_compile_at_starcoder2_widths(
+        one_chip, causal):
+    S = 2048
+    q = _sds(one_chip, (1, S, SC2.num_heads, SC2.hd))
+    kv = _sds(one_chip, (1, S, SC2.num_kv_heads, SC2.hd))
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=causal, impl="pallas")
+
+    def loss(q, k, v):  # squared, so the backward needs the kernel's output
+        return jnp.sum(jnp.square(fwd(q, k, v).astype(jnp.float32)))
+
+    _assert_fits_with_kernel(jax.jit(fwd).lower(q, kv, kv).compile())
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    _assert_fits_with_kernel(grad.lower(q, kv, kv).compile())
+
+
+def test_gla_compiles_at_rwkv6_widths(one_chip):
+    B, H, S, K = 1, 64, 2048, 64
+    x = _sds(one_chip, (B, H, S, K))
+    compiled = jax.jit(lambda q, k, v, w: gla_scan_pallas(
+        q, k, v, w, chunk=128)).lower(x, x, x, x).compile()
+    _assert_fits_with_kernel(compiled)
+
+
+def test_paged_decode_step_compiles_at_16_layers(one_chip, pallas_dispatch):
+    cfg = dataclasses.replace(SC2, num_layers=16)
+    api = build_model(cfg)
+    pshapes, _ = abstract_init(api)
+    page = 128
+    cache = jax.eval_shape(lambda: TF.lm_init_paged_cache(
+        cfg, batch=8, max_len=4096, page=page))
+    del cache["page"]
+
+    def step(params, pools, kv_len, token):
+        return TF.lm_decode_step_paged(params, cfg, dict(pools, page=page),
+                                       kv_len, token)
+
+    compiled = jax.jit(step).lower(
+        _on(one_chip, pshapes), _on(one_chip, cache),
+        _sds(one_chip, (), jnp.int32), _sds(one_chip, (8, 1), jnp.int32),
+    ).compile()
+    _assert_fits_with_kernel(compiled)
+
+
+def test_train_step_compiles_at_1_layer(one_chip, pallas_dispatch):
+    cfg = dataclasses.replace(SC2, num_layers=1)
+    api = build_model(cfg)
+    pshapes, _ = abstract_init(api)
+    f32 = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.float32), pshapes)
+    opt = AdamWState(jax.ShapeDtypeStruct((), jnp.int32), f32, f32)
+    batch = {"tokens": _sds(one_chip, (1, 2048), jnp.int32),
+             "labels": _sds(one_chip, (1, 2048), jnp.int32)}
+    step = make_train_fn(api, TrainConfig())
+    compiled = jax.jit(step).lower(
+        _on(one_chip, pshapes), _on(one_chip, opt), None, batch,
+        _sds(one_chip, (), jnp.int32)).compile()
+    _assert_fits_with_kernel(compiled)

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
-from repro.kernels.flash_attention.ops import flash_attention_xla
+from repro.kernels.flash_attention.ops import flash_attention, flash_attention_xla
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.paged_attention.kernel import paged_attention_pallas
 from repro.kernels.paged_attention.ref import paged_attention_ref
@@ -49,6 +49,31 @@ def test_flash_attention_pallas_interpret(case, dtype):
     np.testing.assert_allclose(out.astype(jnp.float32),
                                ref.astype(jnp.float32),
                                atol=TOL[dtype], rtol=TOL[dtype])
+
+
+# Lengths that are not a multiple of the kernel's 128-row blocks: the
+# wrapper pads them and the kernel masks the padded keys.
+FA_RAGGED_CASES = [
+    # B, Sq, Sk, Hq, Hkv, D, causal
+    (1, 200, 200, 4, 2, 64, True),
+    (1, 200, 200, 4, 2, 64, False),
+    (2, 72, 330, 4, 1, 64, False),
+    (1, 130, 259, 4, 4, 128, True),
+]
+
+
+@pytest.mark.parametrize("case", FA_RAGGED_CASES)
+def test_flash_attention_pallas_pads_ragged_lengths(case):
+    B, Sq, Sk, Hq, Hkv, D, causal = case
+    ks = jax.random.split(jax.random.PRNGKey(10), 3)
+    q = _rand(ks[0], (B, Sq, Hq, D), jnp.float32)
+    k = _rand(ks[1], (B, Sk, Hkv, D), jnp.float32)
+    v = _rand(ks[2], (B, Sk, Hkv, D), jnp.float32)
+    ref = attention_ref(q, k, v, causal=causal)
+    out = flash_attention_pallas(q, k, v, causal=causal, interpret=True)
+    assert out.shape == ref.shape
+    np.testing.assert_allclose(out, ref, atol=TOL[jnp.float32],
+                               rtol=TOL[jnp.float32])
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -214,6 +239,29 @@ def test_flash_attention_xla_gradients_match_naive(case):
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     g_xla = jax.grad(loss_xla, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_ref, g_xla):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("case", [FA_CASES[0], FA_CASES[2],
+                                  (1, 200, 200, 4, 2, 64, True, None)])
+def test_flash_attention_pallas_vjp_matches_xla(case):
+    """The Pallas forward's custom VJP gives the XLA path's gradients."""
+    B, Sq, Sk, Hq, Hkv, D, causal, window = case
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = _rand(ks[0], (B, Sq, Hq, D), jnp.float32)
+    k = _rand(ks[1], (B, Sk, Hkv, D), jnp.float32)
+    v = _rand(ks[2], (B, Sk, Hkv, D), jnp.float32)
+
+    def loss(impl):
+        def f(q, k, v):
+            return jnp.sum(jnp.square(flash_attention(
+                q, k, v, causal=causal, window=window, impl=impl,
+                interpret=True)))
+        return f
+
+    g_pallas = jax.grad(loss("pallas"), argnums=(0, 1, 2))(q, k, v)
+    g_xla = jax.grad(loss("xla_chunked"), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_pallas, g_xla):
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
 
 

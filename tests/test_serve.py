@@ -71,6 +71,23 @@ def test_paged_kv_spill_and_fetch():
     assert eng.hits == 1
 
 
+def test_paged_kv_fetch_offloads_pages_larger_than_one_request():
+    """A real KV page (K and V of 128 tokens x 4 heads x 128 dims, bf16) is
+    larger than the host library's largest request, so each page is written
+    in pieces; the fetch must still be served by the offload path."""
+    block = 2 * 128 * 4 * 128 * 2
+    store = PageStore(page_size=block + 4096, num_pages=8)
+    eng = PagedKVEngine(store, block_bytes=block, hbm_blocks=1)
+    blobs = [np.random.default_rng(i).bytes(block) for i in range(3)]
+    for i, data in enumerate(blobs):
+        eng.put_block(0, 0, i, data)
+    before = store.server.offload.stats.completed
+    for i in range(2):                           # both spilled
+        assert eng.get_block(0, 0, i)[:block] == blobs[i]
+    assert store.server.offload.stats.completed == before + 2
+    assert store.host_served == 0
+
+
 def test_kv_block_versions_respected():
     store = PageStore(page_size=4096, num_pages=256)
     eng = PagedKVEngine(store, block_bytes=1024, hbm_blocks=2)

@@ -9,6 +9,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
 from repro.distributed import sharding as sh
+from repro.launch.mesh import make_mesh
 
 
 class FakeMesh:
@@ -87,8 +88,6 @@ def test_cache_specs_pick_divisible_kv_or_hd():
         "k": jax.ShapeDtypeStruct((40, 128, 32769, 8, 128), np.dtype("bfloat16")),
         "v": jax.ShapeDtypeStruct((40, 128, 32769, 8, 128), np.dtype("bfloat16")),
     }
-    import jax.sharding as js
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
 
     class M:
         shape = {"data": 16, "model": 16}
@@ -104,6 +103,6 @@ def test_cache_specs_pick_divisible_kv_or_hd():
 def test_dp_axes_respects_skip():
     assert sh.dp_axes(MESH3) == ("pod", "data")
     with sh.activation_sharding_scope(
-            jax.make_mesh((1, 1), ("data", "model")),
+            make_mesh((1, 1), ("data", "model")),
             skip_axes=frozenset({"pod"})):
         assert "pod" not in sh.dp_axes(MESH3)
