@@ -1,6 +1,6 @@
 """Benchmark entry point: one module per paper table/figure.
 
-``python -m benchmarks.run``            everything (measured + model + roofline)
+``python -m benchmarks.run``            everything (measured + model)
 ``python -m benchmarks.run fig17``      one module
 ``python -m benchmarks.run --smoke``    CI nightly gate (modules that
                                         support it run reduced sizes)
@@ -27,7 +27,7 @@ from benchmarks import (compare, fig14_16_model, fig17_rings,
                         fig_cluster_scaling, fig_failover, fig_getstorm,
                         fig_hotpath, fig_latency, fig_reshard,
                         fig_scaleout, fig_tenancy, fig_writepath,
-                        kernels_bench, roofline)
+                        kernels_bench)
 
 MODULES = {
     "cluster": fig_cluster_scaling,
@@ -46,7 +46,6 @@ MODULES = {
     "fig22": fig22_cache_table,
     "fig24_26": fig24_26_integration,
     "kernels": kernels_bench,
-    "roofline": roofline,
     "compare": compare,
 }
 

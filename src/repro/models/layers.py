@@ -29,6 +29,15 @@ import jax.numpy as jnp
 from repro.distributed.sharding import gather_fsdp
 from repro.kernels.flash_attention import flash_attention
 
+# The decoder's named scopes (``jax.named_scope``).  Each operation of a
+# compiled prefill or decode step carries the scopes it ran under in its
+# ``op_name`` metadata, so a profiler trace reads layer by layer: ``layers``
+# is the scan over blocks (its own operations slice each layer's weights
+# and cache out of the stack and stack the cache back), ``kv_write`` the
+# cache writes and padding, ``attn_core`` attention over the keys.
+SCOPES = ("embed", "layers", "norm", "attn_qkv", "kv_write", "attn_core",
+          "attn_out", "mlp", "moe", "logits")
+
 # ---------------------------------------------------------------------------
 # Parameter factory with logical axes.
 # ---------------------------------------------------------------------------
@@ -243,7 +252,8 @@ def attention_fwd(params, x, cfg: AttnConfig, positions=None):
     B, S, _ = x.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    q, k, v = _qkv(params, x, cfg, positions)
+    with jax.named_scope("attn_qkv"):
+        q, k, v = _qkv(params, x, cfg, positions)
 
     # Checkpoint the attention op: its chunked online-softmax carries are
     # recomputed in the backward instead of being saved per (layer x chunk)
@@ -253,9 +263,11 @@ def attention_fwd(params, x, cfg: AttnConfig, positions=None):
         lambda q, k, v: flash_attention(
             q, k, v, causal=cfg.causal, window=cfg.window, q_offset=0,
             block_q=cfg.block_q, block_k=cfg.block_k))
-    out = attn(q, k, v)
-    out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
-    return out @ gather_fsdp(params["wo"], tp_dim=0), (k, v)
+    with jax.named_scope("attn_core"):
+        out = attn(q, k, v)
+    with jax.named_scope("attn_out"):
+        out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
+        return out @ gather_fsdp(params["wo"], tp_dim=0), (k, v)
 
 
 def attention_decode(params, x, cfg: AttnConfig, k_cache, v_cache,
@@ -268,18 +280,22 @@ def attention_decode(params, x, cfg: AttnConfig, k_cache, v_cache,
     ring order does not matter).  Returns (out, new_k_cache, new_v_cache).
     """
     B = x.shape[0]
-    q, k_new, v_new = _qkv(params, x, cfg, positions)
+    with jax.named_scope("attn_qkv"):
+        q, k_new, v_new = _qkv(params, x, cfg, positions)
     S_cache = k_cache.shape[1]
-    slot = kv_len % S_cache if cfg.window is not None else kv_len
-    slot = jnp.asarray(slot) % S_cache
-    k_cache = jax.lax.dynamic_update_slice_in_dim(
-        k_cache, k_new.astype(k_cache.dtype), slot, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(
-        v_cache, v_new.astype(v_cache.dtype), slot, axis=1)
-    valid = jnp.minimum(kv_len + 1, S_cache)
-    out = _decode_attend(q, k_cache, v_cache, valid, cfg)
-    out = out.reshape(B, 1, cfg.num_heads * cfg.head_dim)
-    return out @ params["wo"], k_cache, v_cache
+    with jax.named_scope("kv_write"):
+        slot = kv_len % S_cache if cfg.window is not None else kv_len
+        slot = jnp.asarray(slot) % S_cache
+        k_cache = jax.lax.dynamic_update_slice_in_dim(
+            k_cache, k_new.astype(k_cache.dtype), slot, axis=1)
+        v_cache = jax.lax.dynamic_update_slice_in_dim(
+            v_cache, v_new.astype(v_cache.dtype), slot, axis=1)
+    with jax.named_scope("attn_core"):
+        valid = jnp.minimum(kv_len + 1, S_cache)
+        out = _decode_attend(q, k_cache, v_cache, valid, cfg)
+    with jax.named_scope("attn_out"):
+        out = out.reshape(B, 1, cfg.num_heads * cfg.head_dim)
+        return out @ params["wo"], k_cache, v_cache
 
 
 def _decode_attend(q, k_cache, v_cache, valid_len, cfg: AttnConfig):

@@ -64,12 +64,14 @@ def init_block(cfg: ModelConfig, key) -> tuple[dict, dict]:
     return p.params, p.axes
 
 
+@jax.named_scope("norm")
 def _norm1(params, cfg, x):
     if cfg.norm == "rms":
         return L.rms_norm(x, params["norm1"])
     return L.layer_norm(x, params["norm1_w"], params["norm1_b"])
 
 
+@jax.named_scope("norm")
 def _norm2(params, cfg, x):
     if cfg.norm == "rms":
         return L.rms_norm(x, params["norm2"])
@@ -78,10 +80,13 @@ def _norm2(params, cfg, x):
 
 def _mix(params, cfg, h):
     if cfg.family == "moe":
-        return moe_fwd(params["moe"], h, num_experts=cfg.num_experts,
-                       top_k=cfg.top_k, kind=cfg.mlp,
-                       capacity_factor=cfg.capacity_factor)
-    return L.mlp_fwd(params["mlp"], h, cfg.mlp), {"aux_loss": jnp.zeros((), jnp.float32)}
+        with jax.named_scope("moe"):
+            return moe_fwd(params["moe"], h, num_experts=cfg.num_experts,
+                           top_k=cfg.top_k, kind=cfg.mlp,
+                           capacity_factor=cfg.capacity_factor)
+    with jax.named_scope("mlp"):
+        m = L.mlp_fwd(params["mlp"], h, cfg.mlp)
+    return m, {"aux_loss": jnp.zeros((), jnp.float32)}
 
 
 def block_fwd(params, x, cfg: ModelConfig, positions, *,
@@ -148,9 +153,21 @@ def init_lm(cfg: ModelConfig, key) -> tuple[dict, dict]:
 
 
 def _final(params, cfg, x):
-    x = constrain_batch(x)
-    x = L.rms_norm(x, params["final_norm"])
-    return constrain_logits(L.unembed_fwd(params["embedding"], x))
+    with jax.named_scope("norm"):
+        x = L.rms_norm(constrain_batch(x), params["final_norm"])
+    with jax.named_scope("logits"):
+        return constrain_logits(L.unembed_fwd(params["embedding"], x))
+
+
+def _embed(params, tokens, embeds=None):
+    """Token embeddings; ``embeds`` (B, V, d_model), where given, override
+    the first V positions (VLM patch / audio frame stub)."""
+    with jax.named_scope("embed"):
+        x = L.embed_fwd(params["embedding"], tokens)
+        if embeds is not None:
+            V = embeds.shape[1]
+            x = jnp.concatenate([embeds.astype(x.dtype), x[:, V:]], axis=1)
+        return x
 
 
 def _positions(cfg: ModelConfig, B: int, S: int, offset=0):
@@ -172,10 +189,7 @@ def lm_forward(params, cfg: ModelConfig, tokens, embeds=None,
     embeddings (VLM patch / audio frame stub) overriding the first V slots.
     Returns (logits, aux_loss)."""
     B, S = tokens.shape
-    x = L.embed_fwd(params["embedding"], tokens)
-    if embeds is not None:
-        V = embeds.shape[1]
-        x = jnp.concatenate([embeds.astype(x.dtype), x[:, V:]], axis=1)
+    x = _embed(params, tokens, embeds)
     pos = _positions(cfg, B, S)
 
     if cfg.attention == "local_global":
@@ -188,8 +202,9 @@ def lm_forward(params, cfg: ModelConfig, tokens, embeds=None,
 
         if remat:
             body = L.maybe_remat(body, cfg.remat)
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                   params["blocks"])
+        with jax.named_scope("layers"):
+            (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+                                       params["blocks"])
     return _final(params, cfg, x), aux
 
 
@@ -210,8 +225,10 @@ def _forward_local_global(params, cfg, x, pos, remat):
 
     if remat:
         group_body = L.maybe_remat(group_body, cfg.remat)
-    (x, aux), _ = jax.lax.scan(group_body, (x, jnp.zeros((), jnp.float32)),
-                               params["groups"])
+    with jax.named_scope("layers"):
+        (x, aux), _ = jax.lax.scan(group_body,
+                                   (x, jnp.zeros((), jnp.float32)),
+                                   params["groups"])
     if "tail" in params:
         def tail_body(c, blk):
             xx, aa = c
@@ -220,7 +237,8 @@ def _forward_local_global(params, cfg, x, pos, remat):
 
         if remat:
             tail_body = L.maybe_remat(tail_body, cfg.remat)
-        (x, aux), _ = jax.lax.scan(tail_body, (x, aux), params["tail"])
+        with jax.named_scope("layers"):
+            (x, aux), _ = jax.lax.scan(tail_body, (x, aux), params["tail"])
     return x, aux
 
 
@@ -257,7 +275,7 @@ def lm_decode_step(params, cfg: ModelConfig, cache: dict, kv_len, token,
     """token: (B, 1) int32; kv_len: existing valid cache entries.
     Returns (logits (B, vocab), new cache)."""
     B = token.shape[0]
-    x = L.embed_fwd(params["embedding"], token)
+    x = _embed(params, token)
     pos = _positions(cfg, B, 1, offset=kv_len)
 
     if cfg.attention == "local_global":
@@ -268,8 +286,9 @@ def lm_decode_step(params, cfg: ModelConfig, cache: dict, kv_len, token,
             x, kc, vc = block_decode(blk, x, cfg, kc, vc, kv_len, pos)
             return x, (kc, vc)
 
-        x, (k_new, v_new) = jax.lax.scan(
-            body, x, (params["blocks"], cache["k"], cache["v"]))
+        with jax.named_scope("layers"):
+            x, (k_new, v_new) = jax.lax.scan(
+                body, x, (params["blocks"], cache["k"], cache["v"]))
         cache = {"k": k_new, "v": v_new}
     return _final(params, cfg, x)[:, 0], cache
 
@@ -289,9 +308,11 @@ def _decode_local_global(params, cfg, x, cache, kv_len, pos):
                                  theta=cfg.rope_theta_global)
         return x, (lk, lv, gk, gv)
 
-    x, (lk, lv, gk, gv) = jax.lax.scan(
-        group_body, x, (params["groups"], cache["local_k"], cache["local_v"],
-                        cache["global_k"], cache["global_v"]))
+    with jax.named_scope("layers"):
+        x, (lk, lv, gk, gv) = jax.lax.scan(
+            group_body, x, (params["groups"], cache["local_k"],
+                            cache["local_v"], cache["global_k"],
+                            cache["global_v"]))
     new = dict(cache, local_k=lk, local_v=lv, global_k=gk, global_v=gv)
     if "tail" in params:
         def tail_body(x, xs2):
@@ -300,9 +321,10 @@ def _decode_local_global(params, cfg, x, cache, kv_len, pos):
                                      window=cfg.window)
             return x, (kc, vc)
 
-        x, (tk, tv) = jax.lax.scan(tail_body, x,
-                                   (params["tail"], cache["tail_k"],
-                                    cache["tail_v"]))
+        with jax.named_scope("layers"):
+            x, (tk, tv) = jax.lax.scan(tail_body, x,
+                                       (params["tail"], cache["tail_k"],
+                                        cache["tail_v"]))
         new["tail_k"], new["tail_v"] = tk, tv
     return x, new
 
@@ -316,10 +338,7 @@ def lm_prefill(params, cfg: ModelConfig, tokens, cache_len: int | None = None,
     """
     B, S = tokens.shape
     cache_len = cache_len or S
-    x = L.embed_fwd(params["embedding"], tokens)
-    if embeds is not None:
-        V = embeds.shape[1]
-        x = jnp.concatenate([embeds.astype(x.dtype), x[:, V:]], axis=1)
+    x = _embed(params, tokens, embeds)
     pos = _positions(cfg, B, S)
 
     if cfg.attention == "local_global":
@@ -329,19 +348,27 @@ def lm_prefill(params, cfg: ModelConfig, tokens, cache_len: int | None = None,
         x, (k, v), _ = block_fwd(blk, x, cfg, pos)
         return x, (k, v)
 
-    x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
-    pad = cache_len - S
-    if pad > 0:
-        zf = lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-        ks, vs = zf(ks), zf(vs)
+    with jax.named_scope("layers"):
+        x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
+    ks, vs = _pad_cache(ks, cache_len), _pad_cache(vs, cache_len)
     logits = _final(params, cfg, x[:, -1:])[:, 0]
     return logits, {"k": ks, "v": vs}
+
+
+@jax.named_scope("kv_write")
+def _pad_cache(a, cache_len: int):
+    """A stacked (layers, B, S, KV, hd) cache padded to ``cache_len``."""
+    pad = cache_len - a.shape[2]
+    if pad <= 0:
+        return a
+    return jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
 
 
 def _prefill_local_global(params, cfg, x, pos, cache_len):
     W = min(cfg.window, cache_len)
     S_in = x.shape[1]
 
+    @jax.named_scope("kv_write")
     def ring(a):
         """Store position p at ring index p %% W (decode slot convention)."""
         if S_in <= W:  # positions 0..S_in-1 land at indices 0..S_in-1
@@ -359,19 +386,17 @@ def _prefill_local_global(params, cfg, x, pos, cache_len):
                                    theta=cfg.rope_theta_global)
         return x, (lk, lv, gk, gv)
 
-    x, (lk, lv, gk, gv) = jax.lax.scan(group_body, x, params["groups"])
-    S = x.shape[1]
-    pad = cache_len - S
-    if pad > 0:
-        zf = lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-        gk, gv = zf(gk), zf(gv)
+    with jax.named_scope("layers"):
+        x, (lk, lv, gk, gv) = jax.lax.scan(group_body, x, params["groups"])
+    gk, gv = _pad_cache(gk, cache_len), _pad_cache(gv, cache_len)
     cache = {"local_k": lk, "local_v": lv, "global_k": gk, "global_v": gv}
     if "tail" in params:
         def tail_body(x, blk):
             x, (k, v), _ = block_fwd(blk, x, cfg, pos, window=cfg.window)
             return x, (ring(k), ring(v))
 
-        x, (tk, tv) = jax.lax.scan(tail_body, x, params["tail"])
+        with jax.named_scope("layers"):
+            x, (tk, tv) = jax.lax.scan(tail_body, x, params["tail"])
         cache["tail_k"], cache["tail_v"] = tk, tv
     logits = _final(params, cfg, x[:, -1:])[:, 0]
     return logits, cache
@@ -417,7 +442,7 @@ def lm_decode_step_paged(params, cfg: ModelConfig, cache: dict, kv_len,
     B = token.shape[0]
     page = cache["page"]
     table = cache["block_table"]
-    x = L.embed_fwd(params["embedding"], token)
+    x = _embed(params, token)
     pos = _positions(cfg, B, 1, offset=kv_len)
     acfg = _attn_cfg(cfg)
     slot_page = kv_len // page
@@ -427,20 +452,25 @@ def lm_decode_step_paged(params, cfg: ModelConfig, cache: dict, kv_len,
     def body(x, xs):
         blk, k_pool, v_pool = xs
         h = _norm1(blk, cfg, x)
-        q, k_new, v_new = L._qkv(blk["attn"], h, acfg, pos)
+        with jax.named_scope("attn_qkv"):
+            q, k_new, v_new = L._qkv(blk["attn"], h, acfg, pos)
         # Write the new token's K/V into its page (translate-then-write).
-        k_pool = k_pool.at[phys, slot_off].set(
-            k_new[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[phys, slot_off].set(
-            v_new[:, 0].astype(v_pool.dtype))
-        seq_lens = jnp.full((B,), kv_len + 1, jnp.int32)
-        o = paged_attention(q[:, 0], k_pool, v_pool, table, seq_lens)
-        o = o.reshape(B, 1, cfg.num_heads * cfg.hd)
-        x = x + o @ blk["attn"]["wo"]
+        with jax.named_scope("kv_write"):
+            k_pool = k_pool.at[phys, slot_off].set(
+                k_new[:, 0].astype(k_pool.dtype))
+            v_pool = v_pool.at[phys, slot_off].set(
+                v_new[:, 0].astype(v_pool.dtype))
+        with jax.named_scope("attn_core"):
+            seq_lens = jnp.full((B,), kv_len + 1, jnp.int32)
+            o = paged_attention(q[:, 0], k_pool, v_pool, table, seq_lens)
+        with jax.named_scope("attn_out"):
+            o = o.reshape(B, 1, cfg.num_heads * cfg.hd)
+            x = x + o @ blk["attn"]["wo"]
         m, _ = _mix(blk, cfg, _norm2(blk, cfg, x))
         return x + m, (k_pool, v_pool)
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        body, x, (params["blocks"], cache["k_pool"], cache["v_pool"]))
+    with jax.named_scope("layers"):
+        x, (k_pool, v_pool) = jax.lax.scan(
+            body, x, (params["blocks"], cache["k_pool"], cache["v_pool"]))
     new_cache = dict(cache, k_pool=k_pool, v_pool=v_pool)
     return _final(params, cfg, x)[:, 0], new_cache

@@ -17,7 +17,6 @@ leave decode slots between steps.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any
@@ -37,7 +36,8 @@ PREFILL_2D_BYTES = 4 << 30   # 1D-TP weights above this per chip -> go 2D
 
 def make_serve_fns(api: ModelAPI, mesh: Mesh, axes_tree,
                    shape: ShapeConfig, pshapes=None):
-    """Returns (prefill_jit, decode_jit) with explicit shardings.
+    """Returns (prefill_jit, decode_jit) with explicit shardings; their
+    programs are named ``jit_prefill`` and ``jit_decode``.
 
     DECODE always uses 2D weight sharding (model TP x data): weights stay
     stationary on both axes and the tiny decode activations move instead —
@@ -70,7 +70,11 @@ def make_serve_fns(api: ModelAPI, mesh: Mesh, axes_tree,
 
     def decode_jit(cache_like):
         csh = cache_sh(cache_like)
-        return jax.jit(api.decode_step,
+
+        def decode(params, cache, kv_len, token):
+            return api.decode_step(params, cache, kv_len, token)
+
+        return jax.jit(decode,
                        in_shardings=(sh.to_shardings(pspecs, mesh), csh,
                                      ns(P()), row_sh),
                        out_shardings=(row_sh, csh))
@@ -81,9 +85,12 @@ def make_serve_fns(api: ModelAPI, mesh: Mesh, axes_tree,
         bspecs = sh.batch_specs(mesh, shape, api.cfg)
         in_b = {k: ns(bspecs.get(k, P(dp, None))) for k in batch_like}
         in_sh = (sh.to_shardings(pspecs_prefill, mesh), in_b)
-        fn = functools.partial(api.prefill, cache_len=cache_len)
-        _, cache_like = jax.eval_shape(fn, pshapes, batch_like)
-        return jax.jit(fn, in_shardings=in_sh,
+
+        def prefill(params, batch):
+            return api.prefill(params, batch, cache_len=cache_len)
+
+        _, cache_like = jax.eval_shape(prefill, pshapes, batch_like)
+        return jax.jit(prefill, in_shardings=in_sh,
                        out_shardings=(row_sh, cache_sh(cache_like)))
 
     return prefill_jit, decode_jit
