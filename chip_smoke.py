@@ -260,7 +260,8 @@ def phase_serving(key, rng):
         lambda: TF.lm_init_paged_cache(cfg, BATCH, CACHE, PAGE))
     pps = CACHE // PAGE
 
-    def to_pool(x):                     # (L, B, S, KV, hd) -> pages
+    def to_pool(x):                     # (L, B, KV, S, hd) -> pages
+        x = jnp.swapaxes(x, 2, 3)
         return x.reshape(x.shape[0], BATCH * pps, PAGE, *x.shape[3:])
 
     pools = jax.jit(lambda c: {

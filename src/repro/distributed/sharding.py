@@ -157,20 +157,21 @@ def gather_fsdp(w, tp_dim: int | None = None):
 
 
 def constrain_kv_layout(x):
-    """Pin a (..., KV, hd) cache-layout tensor so the model axis sits on
-    whichever of its two trailing dims divides — stops the SPMD partitioner
-    from flip-flopping cache layouts between the decode-attention einsums
-    (its "involuntary full rematerialization" copies the 0.5 GiB cache per
+    """Pin a (..., KV, S, hd) decode cache so the model axis sits on
+    whichever of KV and hd divides — stops the SPMD partitioner from
+    flip-flopping cache layouts between the decode-attention einsums (its
+    "involuntary full rematerialization" copies the 0.5 GiB cache per
     layer; §Perf iteration 11)."""
     mesh = getattr(_ACT_CTX, "mesh", None)
-    if mesh is None or x.ndim < 2 or "model" not in mesh.axis_names:
+    if mesh is None or x.ndim < 3 or "model" not in mesh.axis_names:
         return x
     m = mesh.shape["model"]
-    kv_ax = "model" if x.shape[-2] % m == 0 else None
+    kv_ax = "model" if x.shape[-3] % m == 0 else None
     hd_ax = None if kv_ax else ("model" if x.shape[-1] % m == 0 else None)
     if kv_ax is None and hd_ax is None:
         return x
-    spec = P(*([P.UNCONSTRAINED] * (x.ndim - 2)), kv_ax, hd_ax)
+    spec = P(*([P.UNCONSTRAINED] * (x.ndim - 3)), kv_ax, P.UNCONSTRAINED,
+             hd_ax)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 # Logical-axis -> mesh-axis rule tables.
@@ -283,13 +284,14 @@ def cache_specs(cache_tree: Any, mesh: Mesh, cfg: ModelConfig,
                 shape: ShapeConfig) -> Any:
     """Per-leaf PartitionSpec for KV caches / SSM states, by key pattern.
 
-    Leaf layouts (registry):
-      k/v                (L, B, S, KV, hd)
-      global_k/v         (G, B, S, KV, hd)
-      local_k/v          (G, g-1, B, W, KV, hd)
-      tail_k/v           (T, B, W, KV, hd)
-      cross_k/v          (L, B, S_enc, KV, hd)
-      attn_k/v (hybrid)  (G, B, S, KV, hd)
+    Leaf layouts (registry); decode caches keep each KV head's positions
+    together, the order decode's attention reads (``layers.to_cache``):
+      k/v                (L, B, KV, S, hd)
+      global_k/v         (G, B, KV, S, hd)
+      local_k/v          (G, g-1, B, KV, W, hd)
+      tail_k/v           (T, B, KV, W, hd)
+      attn_k/v (hybrid)  (G, B, KV, S, hd)
+      cross_k/v          (L, B, S_enc, KV, hd)   read by the flash kernel
       groups_conv        (G, E, B, K-1, d_inner)
       groups_gla         (G, E, B, H, state, hd)
       tail_conv/tail_gla (T, B, ...)
@@ -322,14 +324,18 @@ def cache_specs(cache_tree: Any, mesh: Mesh, cfg: ModelConfig,
             return P(*([None] * (nd - 3)), bax, None, model_ax)
         if "gla" in name:            # (..., B, H, state, hd)
             return P(*([None] * (nd - 4)), bax, model_ax, None, None)
-        if nd == 6:                  # (G, g-1, B, W, KV, hd)
-            kv_ax, hd_ax = kv_hd_axes(leaf.shape[4], leaf.shape[5])
-            return P(None, None, bax, None, kv_ax, hd_ax)
-        if nd == 5 and any(t in name for t in ("k", "v")) and "gla" not in name:
-            # (L/G/T, B, S-or-W, KV, hd)
+        if nd == 6:                  # (G, g-1, B, KV, W, hd)
+            kv_ax, hd_ax = kv_hd_axes(leaf.shape[3], leaf.shape[5])
+            return P(None, None, bax, kv_ax, None, hd_ax)
+        if nd == 5 and "cross" in name:  # (L, B, S_enc, KV, hd)
             kv_ax, hd_ax = kv_hd_axes(leaf.shape[3], leaf.shape[4])
             sax = seq_ax if leaf.shape[2] > 4096 else None
             return P(None, bax, sax, kv_ax, hd_ax)
+        if nd == 5 and any(t in name for t in ("k", "v")):
+            # (L/G/T, B, KV, S-or-W, hd)
+            kv_ax, hd_ax = kv_hd_axes(leaf.shape[2], leaf.shape[4])
+            sax = seq_ax if leaf.shape[3] > 4096 else None
+            return P(None, bax, kv_ax, sax, hd_ax)
         # rwkv tuple leaves: (L,B,1,D) or (L,B,H,hd,hd)
         if nd == 4:
             return P(None, bax, None, model_ax)

@@ -147,8 +147,8 @@ def encdec_init_cache(cfg: ModelConfig, batch: int, cache_len: int,
                       enc_len: int, dtype=jnp.bfloat16):
     Ld, KV, hd = cfg.decoder_layers, cfg.num_kv_heads, cfg.hd
     return {
-        "k": jnp.zeros((Ld, batch, cache_len, KV, hd), dtype),
-        "v": jnp.zeros((Ld, batch, cache_len, KV, hd), dtype),
+        "k": jnp.zeros((Ld, batch, KV, cache_len, hd), dtype),
+        "v": jnp.zeros((Ld, batch, KV, cache_len, hd), dtype),
         "cross_k": jnp.zeros((Ld, batch, enc_len, KV, hd), dtype),
         "cross_v": jnp.zeros((Ld, batch, enc_len, KV, hd), dtype),
     }
@@ -170,11 +170,8 @@ def encdec_prefill(params, cfg: ModelConfig, tokens, frames,
         ck, cv = _cross_kv(blk, cfg, enc)
         x = x + _cross_attend(blk, cfg, _ln(blk, "norm2", x), ck, cv)
         m = L.mlp_fwd(blk["mlp"], _ln(blk, "norm3", x), cfg.mlp)
-        pad = cache_len - S
-        if pad > 0:
-            k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        return x + m, (k, v, ck, cv)
+        return x + m, (L.to_cache(k, cache_len), L.to_cache(v, cache_len),
+                       ck, cv)
 
     x, (ks, vs, cks, cvs) = jax.lax.scan(body, x, params["decoder"])
     x = L.rms_norm(x, params["final_norm"])
@@ -190,18 +187,19 @@ def encdec_decode_step(params, cfg: ModelConfig, cache, kv_len, token,
 
     def body(x, xs):
         blk, kc, vc, ck, cv = xs
-        a, kc, vc = L.attention_decode(blk["self_attn"],
-                                       _ln(blk, "norm1", x),
-                                       _self_cfg(cfg, True), kc, vc,
-                                       kv_len, pos)
+        a, k, v = L.attention_decode(blk["self_attn"],
+                                     _ln(blk, "norm1", x),
+                                     _self_cfg(cfg, True), kc, vc,
+                                     kv_len, pos)
         x = x + a
         x = x + _cross_attend(blk, cfg, _ln(blk, "norm2", x), ck, cv)
         m = L.mlp_fwd(blk["mlp"], _ln(blk, "norm3", x), cfg.mlp)
-        return x + m, (kc, vc)
+        return x + m, (k, v)
 
     x, (ks, vs) = jax.lax.scan(body, x, (params["decoder"], cache["k"],
                                          cache["v"], cache["cross_k"],
                                          cache["cross_v"]))
     x = L.rms_norm(x, params["final_norm"])
     logits = L.unembed_fwd(params["embedding"], x)[:, 0]
-    return logits, dict(cache, k=ks, v=vs)
+    return logits, dict(cache, k=L.write_kv(cache["k"], ks, kv_len),
+                        v=L.write_kv(cache["v"], vs, kv_len))

@@ -95,9 +95,9 @@ def hybrid_state(cfg: ModelConfig, batch: int, cache_len: int,
         "groups_gla": carries(n_groups * cfg.attn_every)[1].reshape(
             n_groups, cfg.attn_every, batch, cfg.ssm_heads, cfg.ssm_state,
             hd_m),
-        "attn_k": jnp.zeros((n_groups, batch, cache_len, cfg.num_kv_heads,
+        "attn_k": jnp.zeros((n_groups, batch, cfg.num_kv_heads, cache_len,
                              cfg.hd), dtype),
-        "attn_v": jnp.zeros((n_groups, batch, cache_len, cfg.num_kv_heads,
+        "attn_v": jnp.zeros((n_groups, batch, cfg.num_kv_heads, cache_len,
                              cfg.hd), dtype),
     }
     if tail:
@@ -123,11 +123,11 @@ def _shared_attn_fwd(cfg, sp, x, pos):
 
 
 def _shared_attn_decode(cfg, sp, x, kc, vc, kv_len, pos):
-    a, kc, vc = L.attention_decode(sp["attn"], L.rms_norm(x, sp["norm1"]),
-                                   _attn_cfg(cfg), kc, vc, kv_len, pos)
+    a, k, v = L.attention_decode(sp["attn"], L.rms_norm(x, sp["norm1"]),
+                                 _attn_cfg(cfg), kc, vc, kv_len, pos)
     x = x + a
     m = L.mlp_fwd(sp["mlp"], L.rms_norm(x, sp["norm2"]), cfg.mlp)
-    return x + m, kc, vc
+    return x + m, k, v
 
 
 def hybrid_forward(params, cfg: ModelConfig, tokens, embeds=None,
@@ -175,11 +175,8 @@ def hybrid_prefill(params, cfg: ModelConfig, tokens, cache_len=None,
 
         x, carries = jax.lax.scan(mamba_body, x, grp)
         x, (k, v) = _shared_attn_fwd(cfg, sp, x, pos)
-        pad = cache_len - S
-        if pad > 0:
-            k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        return x, (carries, k, v)
+        return x, (carries, L.to_cache(k, cache_len),
+                   L.to_cache(v, cache_len))
 
     x, (gc, ks, vs) = jax.lax.scan(group_body, x, params["groups"])
     state = {"groups_conv": gc[0], "groups_gla": gc[1],
@@ -212,14 +209,16 @@ def hybrid_decode_step(params, cfg: ModelConfig, state, kv_len, token,
             return x, (nc, ng)
 
         x, (nconv, ngla) = jax.lax.scan(mamba_body, x, (grp, conv, gla))
-        x, kc, vc = _shared_attn_decode(cfg, sp, x, kc, vc, kv_len, pos)
-        return x, (nconv, ngla, kc, vc)
+        x, k, v = _shared_attn_decode(cfg, sp, x, kc, vc, kv_len, pos)
+        return x, (nconv, ngla, k, v)
 
     x, (gc, gg, ks, vs) = jax.lax.scan(
         group_body, x, (params["groups"], state["groups_conv"],
                         state["groups_gla"], state["attn_k"],
                         state["attn_v"]))
-    new = dict(state, groups_conv=gc, groups_gla=gg, attn_k=ks, attn_v=vs)
+    new = dict(state, groups_conv=gc, groups_gla=gg,
+               attn_k=L.write_kv(state["attn_k"], ks, kv_len),
+               attn_v=L.write_kv(state["attn_v"], vs, kv_len))
     if "tail" in params:
         def tail_body(x, xs2):
             blk, c, g = xs2

@@ -33,8 +33,8 @@ from repro.kernels.flash_attention import flash_attention
 # compiled prefill or decode step carries the scopes it ran under in its
 # ``op_name`` metadata, so a profiler trace reads layer by layer: ``layers``
 # is the scan over blocks (its own operations slice each layer's weights
-# and cache out of the stack and stack the cache back), ``kv_write`` the
-# cache writes and padding, ``attn_core`` attention over the keys.
+# and cache out of the stacks), ``kv_write`` the cache writes and padding,
+# ``attn_core`` attention over the keys.
 SCOPES = ("embed", "layers", "norm", "attn_qkv", "kv_write", "attn_core",
           "attn_out", "mlp", "moe", "logits")
 
@@ -270,51 +270,80 @@ def attention_fwd(params, x, cfg: AttnConfig, positions=None):
         return out @ gather_fsdp(params["wo"], tp_dim=0), (k, v)
 
 
+@jax.named_scope("kv_write")
+def to_cache(kv, cache_len: int):
+    """A prefill's keys or values, (..., B, S, KV, hd), as a decode cache:
+    (..., B, KV, cache_len, hd), zero past S.  The cache keeps each head's
+    positions together, the order in which decode's attention reads them,
+    so the decode step reads its cache where it lies."""
+    a = jnp.swapaxes(kv, -3, -2)
+    pad = cache_len - a.shape[-2]
+    if pad <= 0:
+        return a
+    return jnp.pad(a, [(0, 0)] * (a.ndim - 2) + [(0, pad), (0, 0)])
+
+
 def attention_decode(params, x, cfg: AttnConfig, k_cache, v_cache,
                      kv_len: int, positions):
-    """One-token decode against a filled cache.
+    """One-token decode against a filled cache, which it reads and does
+    not write.
 
-    x: (B, 1, D); k_cache/v_cache: (B, S_cache, KV, hd) where entries
-    [0, kv_len) are valid roped keys.  For sliding-window layers the cache
-    is a ring of size ``window`` (attention is permutation-invariant, so
-    ring order does not matter).  Returns (out, new_k_cache, new_v_cache).
+    x: (B, 1, D); k_cache/v_cache: (B, KV, S_cache, hd) (``to_cache``),
+    where position p lies in slot p % S_cache (a ring once the cache is
+    full: sliding-window layers keep a ring of size ``window``) and entries
+    of positions before ``kv_len`` are valid roped keys.  The query attends
+    over those entries and its own new entry, held apart.  Returns (out,
+    k_new, v_new), the new entries (B, KV, 1, hd) in the cache's dtype; the
+    caller writes them at ``kv_len`` with ``write_kv``, after its scan over
+    layers, so that the stacked cache is written in place and never copied.
     """
     B = x.shape[0]
     with jax.named_scope("attn_qkv"):
         q, k_new, v_new = _qkv(params, x, cfg, positions)
-    S_cache = k_cache.shape[1]
-    with jax.named_scope("kv_write"):
-        slot = kv_len % S_cache if cfg.window is not None else kv_len
-        slot = jnp.asarray(slot) % S_cache
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            k_cache, k_new.astype(k_cache.dtype), slot, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            v_cache, v_new.astype(v_cache.dtype), slot, axis=1)
+        k_new = jnp.swapaxes(k_new, 1, 2).astype(k_cache.dtype)
+        v_new = jnp.swapaxes(v_new, 1, 2).astype(v_cache.dtype)
     with jax.named_scope("attn_core"):
-        valid = jnp.minimum(kv_len + 1, S_cache)
-        out = _decode_attend(q, k_cache, v_cache, valid, cfg)
+        out = _decode_attend(q, k_cache, v_cache, k_new, v_new, kv_len)
     with jax.named_scope("attn_out"):
         out = out.reshape(B, 1, cfg.num_heads * cfg.head_dim)
-        return out @ params["wo"], k_cache, v_cache
+        return out @ params["wo"], k_new, v_new
 
 
-def _decode_attend(q, k_cache, v_cache, valid_len, cfg: AttnConfig):
-    """Masked non-causal attention of one query over the cache (fp32 softmax)."""
+@jax.named_scope("kv_write")
+def write_kv(cache, new, kv_len):
+    """Write the new entries ``new`` (``cache``'s shape but for one
+    position) at position ``kv_len``: slot ``kv_len % S`` of a cache of S
+    positions."""
+    slot = jnp.asarray(kv_len) % cache.shape[-2]
+    return jax.lax.dynamic_update_slice_in_dim(cache, new.astype(cache.dtype),
+                                               slot, axis=cache.ndim - 2)
+
+
+def _decode_attend(q, k_cache, v_cache, k_new, v_new, kv_len):
+    """Non-causal attention of one query over the cache's entries of
+    positions before ``kv_len`` and the new entry: one fp32 softmax over
+    both.  Once the ring is full, the slot the new entry will overwrite
+    (``kv_len % S``) holds the oldest position, which is masked."""
     from repro.distributed.sharding import constrain_kv_layout
     B, _, H, hd = q.shape
-    KV = k_cache.shape[2]
+    KV, S = k_cache.shape[1], k_cache.shape[2]
     G = H // KV
     qf = q.astype(jnp.float32) * (hd ** -0.5)           # (B,1,H,hd)
     kf = constrain_kv_layout(k_cache.astype(jnp.float32))
     vf = constrain_kv_layout(v_cache.astype(jnp.float32))
     qg = qf.reshape(B, KV, G, hd)
-    s = jnp.einsum("bkgd,bskd->bkgs", qg, kf)           # (B,KV,G,S)
-    kpos = jnp.arange(k_cache.shape[1])
-    mask = kpos[None, None, None, :] < valid_len
-    s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgs,bskd->bkgd", p, vf)
-    return o.reshape(B, 1, H, hd).astype(q.dtype)
+    s = jnp.einsum("bkgd,bksd->bkgs", qg, kf)           # (B,KV,G,S)
+    kpos = jnp.arange(S)
+    old = (kpos < kv_len) & (kpos != jnp.asarray(kv_len) % S)
+    s = jnp.where(old[None, None, None, :], s, -1e30)
+    kn = k_new[:, :, 0].astype(jnp.float32)             # (B,KV,hd)
+    vn = v_new[:, :, 0].astype(jnp.float32)
+    s_new = jnp.einsum("bkgd,bkd->bkg", qg, kn)[..., None]
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), s_new)
+    p, p_new = jnp.exp(s - m), jnp.exp(s_new - m)
+    den = jnp.sum(p, axis=-1, keepdims=True) + p_new
+    o = jnp.einsum("bkgs,bksd->bkgd", p, vf) + p_new * vn[:, :, None, :]
+    return (o / den).reshape(B, 1, H, hd).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -102,14 +102,18 @@ def block_fwd(params, x, cfg: ModelConfig, positions, *,
 
 
 def block_decode(params, x, cfg: ModelConfig, k_cache, v_cache, kv_len,
-                 positions, *, window=None, theta=None):
-    acfg = _attn_cfg(cfg, window=window, theta=theta)
-    a, k_cache, v_cache = L.attention_decode(
+                 positions, *, theta=None):
+    """One token through a block that reads its layer's cache (a
+    sliding-window layer's is a ring of the window's size).  Returns (x,
+    k_new, v_new): the new entries, which the caller writes after its scan
+    (``L.write_kv``)."""
+    acfg = _attn_cfg(cfg, theta=theta)
+    a, k_new, v_new = L.attention_decode(
         params["attn"], _norm1(params, cfg, x), acfg, k_cache, v_cache,
         kv_len, positions)
     x = x + a
     m, _ = _mix(params, cfg, _norm2(params, cfg, x))
-    return x + m, k_cache, v_cache
+    return x + m, k_new, v_new
 
 
 # ---------------------------------------------------------------------------
@@ -256,24 +260,29 @@ def lm_init_cache(cfg: ModelConfig, batch: int, cache_len: int,
         tail = cfg.num_layers - n_groups * gsz
         W = min(cfg.window, cache_len)
         cache = {
-            "local_k": jnp.zeros((n_groups, gsz - 1, batch, W, KV, hd), dtype),
-            "local_v": jnp.zeros((n_groups, gsz - 1, batch, W, KV, hd), dtype),
-            "global_k": jnp.zeros((n_groups, batch, cache_len, KV, hd), dtype),
-            "global_v": jnp.zeros((n_groups, batch, cache_len, KV, hd), dtype),
+            "local_k": jnp.zeros((n_groups, gsz - 1, batch, KV, W, hd), dtype),
+            "local_v": jnp.zeros((n_groups, gsz - 1, batch, KV, W, hd), dtype),
+            "global_k": jnp.zeros((n_groups, batch, KV, cache_len, hd), dtype),
+            "global_v": jnp.zeros((n_groups, batch, KV, cache_len, hd), dtype),
         }
         if tail:
-            cache["tail_k"] = jnp.zeros((tail, batch, W, KV, hd), dtype)
-            cache["tail_v"] = jnp.zeros((tail, batch, W, KV, hd), dtype)
+            cache["tail_k"] = jnp.zeros((tail, batch, KV, W, hd), dtype)
+            cache["tail_v"] = jnp.zeros((tail, batch, KV, W, hd), dtype)
         return cache
     Lr = cfg.num_layers
-    return {"k": jnp.zeros((Lr, batch, cache_len, KV, hd), dtype),
-            "v": jnp.zeros((Lr, batch, cache_len, KV, hd), dtype)}
+    return {"k": jnp.zeros((Lr, batch, KV, cache_len, hd), dtype),
+            "v": jnp.zeros((Lr, batch, KV, cache_len, hd), dtype)}
 
 
 def lm_decode_step(params, cfg: ModelConfig, cache: dict, kv_len, token,
                    embeds=None):
     """token: (B, 1) int32; kv_len: existing valid cache entries.
-    Returns (logits (B, vocab), new cache)."""
+    Returns (logits (B, vocab), new cache).
+
+    The scan over layers reads each layer's cache where it lies and emits
+    only the layer's new K and V entry; one write a leaf after the scan
+    puts them at ``kv_len``.  With the cache donated, that write is in
+    place and the step copies no cache."""
     B = token.shape[0]
     x = _embed(params, token)
     pos = _positions(cfg, B, 1, offset=kv_len)
@@ -283,25 +292,28 @@ def lm_decode_step(params, cfg: ModelConfig, cache: dict, kv_len, token,
     else:
         def body(x, blk_cache):
             blk, kc, vc = blk_cache
-            x, kc, vc = block_decode(blk, x, cfg, kc, vc, kv_len, pos)
-            return x, (kc, vc)
+            x, k, v = block_decode(blk, x, cfg, kc, vc, kv_len, pos)
+            return x, (k, v)
 
         with jax.named_scope("layers"):
-            x, (k_new, v_new) = jax.lax.scan(
+            x, (k, v) = jax.lax.scan(
                 body, x, (params["blocks"], cache["k"], cache["v"]))
-        cache = {"k": k_new, "v": v_new}
+        cache = {"k": L.write_kv(cache["k"], k, kv_len),
+                 "v": L.write_kv(cache["v"], v, kv_len)}
     return _final(params, cfg, x)[:, 0], cache
 
 
 def _decode_local_global(params, cfg, x, cache, kv_len, pos):
+    """As the uniform path: the scans read the rings and the global caches
+    and emit each layer's new entries, written after them."""
     def group_body(x, xs):
         grp, lk, lv, gk, gv = xs
 
         def local_body(x, xs2):
             blk, kc, vc = xs2
-            x, kc, vc = block_decode(blk, x, cfg, kc, vc, kv_len, pos,
-                                     window=cfg.window, theta=cfg.rope_theta)
-            return x, (kc, vc)
+            x, k, v = block_decode(blk, x, cfg, kc, vc, kv_len, pos,
+                                   theta=cfg.rope_theta)
+            return x, (k, v)
 
         x, (lk, lv) = jax.lax.scan(local_body, x, (grp["local"], lk, lv))
         x, gk, gv = block_decode(grp["global"], x, cfg, gk, gv, kv_len, pos,
@@ -313,19 +325,23 @@ def _decode_local_global(params, cfg, x, cache, kv_len, pos):
             group_body, x, (params["groups"], cache["local_k"],
                             cache["local_v"], cache["global_k"],
                             cache["global_v"]))
-    new = dict(cache, local_k=lk, local_v=lv, global_k=gk, global_v=gv)
+    new = dict(cache,
+               local_k=L.write_kv(cache["local_k"], lk, kv_len),
+               local_v=L.write_kv(cache["local_v"], lv, kv_len),
+               global_k=L.write_kv(cache["global_k"], gk, kv_len),
+               global_v=L.write_kv(cache["global_v"], gv, kv_len))
     if "tail" in params:
         def tail_body(x, xs2):
             blk, kc, vc = xs2
-            x, kc, vc = block_decode(blk, x, cfg, kc, vc, kv_len, pos,
-                                     window=cfg.window)
-            return x, (kc, vc)
+            x, k, v = block_decode(blk, x, cfg, kc, vc, kv_len, pos)
+            return x, (k, v)
 
         with jax.named_scope("layers"):
             x, (tk, tv) = jax.lax.scan(tail_body, x,
                                        (params["tail"], cache["tail_k"],
                                         cache["tail_v"]))
-        new["tail_k"], new["tail_v"] = tk, tv
+        new["tail_k"] = L.write_kv(cache["tail_k"], tk, kv_len)
+        new["tail_v"] = L.write_kv(cache["tail_v"], tv, kv_len)
     return x, new
 
 
@@ -350,18 +366,9 @@ def lm_prefill(params, cfg: ModelConfig, tokens, cache_len: int | None = None,
 
     with jax.named_scope("layers"):
         x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
-    ks, vs = _pad_cache(ks, cache_len), _pad_cache(vs, cache_len)
+    ks, vs = L.to_cache(ks, cache_len), L.to_cache(vs, cache_len)
     logits = _final(params, cfg, x[:, -1:])[:, 0]
     return logits, {"k": ks, "v": vs}
-
-
-@jax.named_scope("kv_write")
-def _pad_cache(a, cache_len: int):
-    """A stacked (layers, B, S, KV, hd) cache padded to ``cache_len``."""
-    pad = cache_len - a.shape[2]
-    if pad <= 0:
-        return a
-    return jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
 
 
 def _prefill_local_global(params, cfg, x, pos, cache_len):
@@ -370,10 +377,10 @@ def _prefill_local_global(params, cfg, x, pos, cache_len):
 
     @jax.named_scope("kv_write")
     def ring(a):
-        """Store position p at ring index p %% W (decode slot convention)."""
+        """Store position p at ring index p % W (decode slot convention)."""
         if S_in <= W:  # positions 0..S_in-1 land at indices 0..S_in-1
-            return jnp.pad(a, ((0, 0), (0, W - S_in), (0, 0), (0, 0)))
-        return jnp.roll(a[:, -W:], S_in % W, axis=1)
+            return L.to_cache(a, W)
+        return jnp.roll(L.to_cache(a[:, -W:], W), S_in % W, axis=2)
 
     def group_body(x, grp):
         def local_body(x, blk):
@@ -388,7 +395,7 @@ def _prefill_local_global(params, cfg, x, pos, cache_len):
 
     with jax.named_scope("layers"):
         x, (lk, lv, gk, gv) = jax.lax.scan(group_body, x, params["groups"])
-    gk, gv = _pad_cache(gk, cache_len), _pad_cache(gv, cache_len)
+    gk, gv = L.to_cache(gk, cache_len), L.to_cache(gv, cache_len)
     cache = {"local_k": lk, "local_v": lv, "global_k": gk, "global_v": gv}
     if "tail" in params:
         def tail_body(x, blk):

@@ -37,7 +37,9 @@ PREFILL_2D_BYTES = 4 << 30   # 1D-TP weights above this per chip -> go 2D
 def make_serve_fns(api: ModelAPI, mesh: Mesh, axes_tree,
                    shape: ShapeConfig, pshapes=None):
     """Returns (prefill_jit, decode_jit) with explicit shardings; their
-    programs are named ``jit_prefill`` and ``jit_decode``.
+    programs are named ``jit_prefill`` and ``jit_decode``.  Decode donates
+    its cache (argument 1): the step writes its new entries into it in
+    place, and the caller passes each step the cache the last one returned.
 
     DECODE always uses 2D weight sharding (model TP x data): weights stay
     stationary on both axes and the tiny decode activations move instead —
@@ -77,7 +79,7 @@ def make_serve_fns(api: ModelAPI, mesh: Mesh, axes_tree,
         return jax.jit(decode,
                        in_shardings=(sh.to_shardings(pspecs, mesh), csh,
                                      ns(P()), row_sh),
-                       out_shardings=(row_sh, csh))
+                       out_shardings=(row_sh, csh), donate_argnums=(1,))
 
     def prefill_jit(batch_like, cache_len: int | None = None):
         """The prefill's cache leaves with decode's cache shardings, so

@@ -13,20 +13,26 @@ this file.
 """
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import get_config
+from repro.configs import ShapeConfig, get_config
+from repro.distributed import sharding as sh
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.paged_attention.kernel import paged_attention_pallas
 from repro.kernels.ssm_scan.kernel import gla_scan_pallas
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as TF
 from repro.models.registry import build_model
 from repro.optim import AdamWState
+from repro.serve.engine import make_serve_fns
 from repro.train.loop import TrainConfig, abstract_init, make_train_fn
+from test_decode_in_place import (KEEP, aliases, entry_name, in_place_write,
+                                  parse_hlo, while_bodies)
 
 HBM_BYTES = 16 * 1024 ** 3          # one v5e chip
 SC2 = get_config("starcoder2_7b")    # Hq 36, Hkv 4, head dim 128
@@ -158,3 +164,41 @@ def test_train_step_compiles_at_1_layer(one_chip, pallas_dispatch):
         _on(one_chip, pshapes), _on(one_chip, opt), None, batch,
         _sds(one_chip, (), jnp.int32)).compile()
     _assert_fits_with_kernel(compiled)
+
+
+def test_serving_decode_keeps_its_cache_in_place_at_16_layers(topo):
+    """``make_serve_fns``' decode at the sc2-decode cell's shapes (16
+    layers, 16 slots, a 4096-entry cache): the donated cache aliases the
+    output; no operation in the scan's loop makes an array as large as one
+    layer's cache (no slice copied out, relaid out or stacked back); and
+    outside the loop the stacked cache is only written in place."""
+    cfg = dataclasses.replace(SC2, num_layers=16)
+    api = build_model(cfg)
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    pshapes, axes = abstract_init(api)
+    B, S, CACHE = 16, 1020, 4096
+    _, decode_jit = make_serve_fns(
+        api, mesh, axes, ShapeConfig("sc2", "prefill", S, B), pshapes)
+    batch = {"tokens": jax.ShapeDtypeStruct((B, S), jnp.int32)}
+    cache = jax.eval_shape(
+        lambda p, b: api.prefill(p, b, cache_len=CACHE), pshapes, batch)[1]
+    step = (jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1), jnp.int32))
+    with mesh, sh.activation_sharding_scope(mesh, "decode"):
+        compiled = decode_jit(cache).lower(pshapes, cache, *step).compile()
+    text = compiled.as_text()
+    n_weights = len(jax.tree_util.tree_leaves(pshapes))
+    assert aliases(text) == {1: n_weights, 2: n_weights + 1}
+    cache_bytes = 2 * cache["k"].size * cache["k"].dtype.itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 64
+
+    comps = parse_hlo(text)
+    layer = cache["k"].size // cfg.num_layers
+    big = [i["name"] for b in while_bodies(text) for i in comps[b]
+           if i["op"] not in KEEP and math.prod(i["dims"]) >= layer]
+    assert big == []
+    made = [i for i in comps[entry_name(text)]
+            if i["dims"] == cache["k"].shape and i["op"] not in KEEP]
+    assert len(made) == 2 and all(in_place_write(comps, i) for i in made)

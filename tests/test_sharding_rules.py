@@ -85,8 +85,8 @@ def test_cache_specs_pick_divisible_kv_or_hd():
     cfg = get_config("dbrx_132b")   # kv=8 (not /16), hd=128 (/16)
     from repro.configs import SHAPES
     cache = {
-        "k": jax.ShapeDtypeStruct((40, 128, 32769, 8, 128), np.dtype("bfloat16")),
-        "v": jax.ShapeDtypeStruct((40, 128, 32769, 8, 128), np.dtype("bfloat16")),
+        "k": jax.ShapeDtypeStruct((40, 128, 8, 32769, 128), np.dtype("bfloat16")),
+        "v": jax.ShapeDtypeStruct((40, 128, 8, 32769, 128), np.dtype("bfloat16")),
     }
 
     class M:
@@ -96,7 +96,7 @@ def test_cache_specs_pick_divisible_kv_or_hd():
     specs = sh.cache_specs(cache, M(), cfg, SHAPES["decode_32k"])
     for s in jax.tree_util.tree_leaves(
             specs, is_leaf=lambda x: isinstance(x, P)):
-        assert s[3] is None          # kv heads 8 can't take model=16
+        assert s[2] is None          # kv heads 8 can't take model=16
         assert s[4] == "model"       # head_dim 128 can
 
 
