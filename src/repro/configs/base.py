@@ -34,10 +34,16 @@ class ModelConfig:
     qkv_bias: bool = False
     mrope: bool = False
     tie_embeddings: bool = False
+    # Granite's multipliers; each default is the identity, which the
+    # program skips (no ``* 1.0`` in its compiled steps)
+    embedding_multiplier: float = 1.0    # token embeddings scaled by this
+    attention_multiplier: float | None = None   # score scale; None: 1/sqrt(hd)
+    residual_multiplier: float = 1.0     # each block's outputs, before the add
+    logits_scaling: float = 1.0          # logits divided by this
     # MoE
     num_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float | None = 1.25   # None: dropless
     # SSM / hybrid
     ssm_kind: str = ""                   # mamba2 | rwkv6
     ssm_state: int = 0

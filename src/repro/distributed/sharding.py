@@ -112,6 +112,44 @@ def per_shard_attention(attn):
     return sharded
 
 
+def per_shard_experts(layer, params, x, ff_dims: dict[str, int]):
+    """``layer(params, x) -> (out, aux)``, an expert layer, run shard by
+    shard under a multi-device scope (``shard_map``): x's batch over the
+    data-parallel axes where it divides, and each weight named in
+    ``ff_dims`` sliced over ``model`` along its d_ff dim, where that
+    divides.  Each data shard routes and sorts only its own tokens; each
+    model shard's partial output is summed over ``model``, and ``aux``'s
+    arrays are averaged over the shards.  Outside a scope, or on one
+    device, ``layer`` runs as it is."""
+    mesh = getattr(_ACT_CTX, "mesh", None)
+    if mesh is None or mesh.size == 1:
+        return layer(params, x)
+    manual = frozenset(mesh.axis_names) - getattr(_ACT_CTX, "skip_axes",
+                                                  frozenset())
+    dp = dp_axes(mesh)
+    bax = dp if dp and x.shape[0] % _axis_size(mesh, dp) == 0 else None
+    tp = "model" in manual and all(
+        params[k].shape[d] % mesh.shape["model"] == 0
+        for k, d in ff_dims.items() if k in params)
+    pspecs = {k: P(*("model" if tp and i == ff_dims.get(k) else None
+                     for i in range(v.ndim))) for k, v in params.items()}
+    mode = getattr(_ACT_CTX, "mode", "train")
+
+    def body(p, xs):
+        # every axis is manual in here: no sharding constraint may name one
+        with activation_sharding_scope(mesh, mode,
+                                       skip_axes=frozenset(mesh.axis_names)):
+            out, aux = layer(p, xs)
+        if tp:
+            out = jax.lax.psum(out, "model")
+        return out, jax.tree_util.tree_map(
+            lambda a: jax.lax.pmean(a, tuple(manual)), aux)
+
+    return jax.shard_map(body, mesh=mesh, in_specs=(pspecs, P(bax)),
+                         out_specs=(P(bax), P()), axis_names=set(manual),
+                         check_vma=False)(params, x)
+
+
 def constrain_logits(x):
     """Logits: batch over the DP axes AND vocab over the model axis.
     (Batch-only pinning replicates the vocab dim — a 64 GiB/device fp32

@@ -197,6 +197,7 @@ class AttnConfig:
     mrope: bool = False
     causal: bool = True
     window: int | None = None    # sliding window (None = full)
+    scale: float | None = None   # score scale (None = 1/sqrt(head_dim))
     block_q: int = 512
     block_k: int = 512
 
@@ -262,7 +263,7 @@ def attention_fwd(params, x, cfg: AttnConfig, positions=None):
     attn = jax.checkpoint(
         lambda q, k, v: flash_attention(
             q, k, v, causal=cfg.causal, window=cfg.window, q_offset=0,
-            block_q=cfg.block_q, block_k=cfg.block_k))
+            scale=cfg.scale, block_q=cfg.block_q, block_k=cfg.block_k))
     with jax.named_scope("attn_core"):
         out = attn(q, k, v)
     with jax.named_scope("attn_out"):
@@ -303,7 +304,8 @@ def attention_decode(params, x, cfg: AttnConfig, k_cache, v_cache,
         k_new = jnp.swapaxes(k_new, 1, 2).astype(k_cache.dtype)
         v_new = jnp.swapaxes(v_new, 1, 2).astype(v_cache.dtype)
     with jax.named_scope("attn_core"):
-        out = _decode_attend(q, k_cache, v_cache, k_new, v_new, kv_len)
+        out = _decode_attend(q, k_cache, v_cache, k_new, v_new, kv_len,
+                             cfg.scale)
     with jax.named_scope("attn_out"):
         out = out.reshape(B, 1, cfg.num_heads * cfg.head_dim)
         return out @ params["wo"], k_new, v_new
@@ -319,16 +321,17 @@ def write_kv(cache, new, kv_len):
                                                slot, axis=cache.ndim - 2)
 
 
-def _decode_attend(q, k_cache, v_cache, k_new, v_new, kv_len):
+def _decode_attend(q, k_cache, v_cache, k_new, v_new, kv_len, scale=None):
     """Non-causal attention of one query over the cache's entries of
     positions before ``kv_len`` and the new entry: one fp32 softmax over
-    both.  Once the ring is full, the slot the new entry will overwrite
-    (``kv_len % S``) holds the oldest position, which is masked."""
+    both, of scores scaled by ``scale`` (1/sqrt(hd) by default).  Once the
+    ring is full, the slot the new entry will overwrite (``kv_len % S``)
+    holds the oldest position, which is masked."""
     from repro.distributed.sharding import constrain_kv_layout
     B, _, H, hd = q.shape
     KV, S = k_cache.shape[1], k_cache.shape[2]
     G = H // KV
-    qf = q.astype(jnp.float32) * (hd ** -0.5)           # (B,1,H,hd)
+    qf = q.astype(jnp.float32) * (hd ** -0.5 if scale is None else scale)
     kf = constrain_kv_layout(k_cache.astype(jnp.float32))
     vf = constrain_kv_layout(v_cache.astype(jnp.float32))
     qg = qf.reshape(B, KV, G, hd)
@@ -399,7 +402,11 @@ def embed_fwd(params, tokens):
     return jnp.take(params["embed"], tokens, axis=0)
 
 
-def unembed_fwd(params, x):
-    if "unembed" in params:
-        return x @ params["unembed"]
-    return x @ params["embed"].T  # tied
+def unembed_fwd(params, x, scaling: float = 1.0):
+    """Logits of ``x``, divided by ``scaling`` while still in float32 (one
+    rounding to ``x``'s dtype, after the division)."""
+    w = params["unembed"] if "unembed" in params else params["embed"].T
+    if scaling == 1.0:
+        return x @ w
+    out = jnp.dot(x, w, preferred_element_type=jnp.float32) / scaling
+    return out.astype(x.dtype)

@@ -1,26 +1,45 @@
-"""Mixture-of-Experts layer (granite-moe, dbrx) with sort-based dispatch.
+"""Mixture-of-Experts layer (granite-moe, dbrx): float32 routing, then the
+experts as grouped matmuls over the assignments sorted by expert.
 
-Top-k routing with capacity: token->expert assignments are argsorted by
-expert id, scattered into per-expert buffers of capacity
-``C = ceil(T * top_k / E * capacity_factor)``, run through batched expert
-FFNs — einsum over the (experts, capacity, d) buffer so the expert dim can
-be sharded over the model axis (expert parallelism) — and gathered back with
-router-probability weighting.  Tokens beyond an expert's capacity are
-dropped (standard capacity-based MoE; the auxiliary load-balance loss keeps
-drops rare).
+The router's logits accumulate in float32; top-k picks from them and the
+gates are the softmax of the k chosen logits.  The B*S*k token->expert
+assignments are sorted by expert (stably, so each expert's assignments
+keep token order), and ``jax.lax.ragged_dot`` runs the gate, up and down
+projections over the sorted rows, each expert's weights over its own run
+of rows: one grouped matmul each (on a TPU a Mosaic kernel), with no
+(tokens, experts, ...) dispatch tensor and no capacity buffers.  The
+results go back to token order and are summed under the gates.
 
-This avoids the (tokens, E, C) one-hot dispatch tensor, whose memory is
-infeasible at 32k-sequence scale; memory here is O(E * C * d) = the expert
-buffers themselves.
+``capacity_factor=None`` is dropless.  A number keeps capacity-based
+routing: within each sequence, expert e takes at most
+C = ceil(S * k * capacity_factor / E) of its assignments, first come first
+served, and the rest are dropped (their gate zeroed).  That is a mask on
+the same sorted assignments, not a second path.  A decode step (S = 1)
+drops nothing, since a token's k experts are distinct and C >= 1.
+
+Under a mesh the layer runs shard by shard (``sharding.per_shard_experts``):
+each data shard sorts only its own tokens, so no sort or gather crosses
+data shards, and each model shard computes its slice of d_ff.
+
+Named scopes, inside the caller's ``moe``: ``moe_route`` (router, top-k,
+sort, group sizes and the gather of the sorted rows), ``moe_experts`` (the
+three grouped matmuls) and ``moe_combine`` (back to token order and the
+gated sum).
 """
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import constrain_batch, gather_fsdp
+from repro.distributed.sharding import per_shard_experts
 from repro.models.layers import ParamFactory
+
+# Each weight's d_ff dim, the one a model shard holds a slice of.
+FF_DIMS = {"wi_gate": 2, "wi_up": 2, "wo": 1}
 
 
 def init_moe(key, d_model: int, d_ff: int, num_experts: int, top_k: int,
@@ -38,81 +57,68 @@ def init_moe(key, d_model: int, d_ff: int, num_experts: int, top_k: int,
 
 
 def moe_fwd(params, x, *, num_experts: int, top_k: int,
-            kind: str = "swiglu", capacity_factor: float = 1.25):
-    """x: (B, S, D) -> (out, aux) where aux has the load-balancing loss.
+            kind: str = "swiglu", capacity_factor: float | None = 1.25):
+    """x: (B, S, D) -> (out, aux); aux holds the Switch-style load-balance
+    loss (``aux_loss``) and the share of assignments dropped
+    (``dropped_frac``, 0 when dropless)."""
+    layer = functools.partial(_moe_local, num_experts=num_experts,
+                              top_k=top_k, kind=kind,
+                              capacity_factor=capacity_factor)
+    out, stats = per_shard_experts(layer, params, x, FF_DIMS)
+    # the loss from the whole batch's shares, which equal-sized shards'
+    # means average to
+    return out, {"aux_loss": num_experts * jnp.sum(stats["fe"] * stats["me"]),
+                 "dropped_frac": stats["dropped_frac"]}
 
-    Dispatch is PER BATCH ROW (vmapped over B): sort, position-in-expert,
-    scatter and gather all act within one row, so with the batch dim
-    data-sharded every dispatch op partitions locally — no global sort
-    network, no cross-shard gathers (the naive global-token dispatch cost
-    192 GiB of all-gather per step on dbrx train_4k; §Perf iteration 8).
-    Per-row capacity C = S*K/E * cf bounds compute overhead at exactly the
-    capacity factor.  Expert weights are laid out (E, D, F) with F
-    TP-sharded and D FSDP-sharded ("ff"/"embed" axes): every device holds a
-    slice of EVERY expert, so no token ever crosses the model axis.
-    """
+
+def route(params, x, top_k: int):
+    """float32 router logits (B, S, E), and the top-k experts (B, S, k)
+    with their gates, the softmax of the k chosen logits."""
+    logits = jnp.dot(x, params["router"], preferred_element_type=jnp.float32)
+    top, idx = jax.lax.top_k(logits, top_k)
+    return logits, idx, jax.nn.softmax(top, axis=-1)
+
+
+def _moe_local(params, x, *, num_experts, top_k, kind, capacity_factor):
     B, S, D = x.shape
     E, K = num_experts, top_k
-    logits = (x @ params["router"]).astype(jnp.float32)        # (B, S, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)              # (B, S, K)
-    gate_vals = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-9)
-
-    # Load-balance loss (Switch-style): E * sum_e f_e * p_e.
-    me = probs.mean(axis=(0, 1))
-    fe = jax.nn.one_hot(gate_idx[..., 0], E,
-                        dtype=jnp.float32).mean(axis=(0, 1))
-    aux_loss = E * jnp.sum(fe * me)
-
-    A = S * K
-    C = int(max(1, -(-A * capacity_factor // E)))
-
-    def dispatch_row(xr, exp_r, gate_r):
-        """One batch row: xr (S, D); exp_r/gate_r (S, K)."""
-        flat_exp = exp_r.reshape(A)
-        flat_tok = jnp.repeat(jnp.arange(S), K)
-        flat_gate = gate_r.reshape(A)
-        order = jnp.argsort(flat_exp)
+    T, A = B * S, B * S * K
+    with jax.named_scope("moe_route"):
+        logits, idx, gates = route(params, x, K)
+        flat_exp = idx.reshape(A)
+        order = jnp.argsort(flat_exp, stable=True)
         sexp = flat_exp[order]
-        stok = flat_tok[order]
-        sgate = flat_gate[order]
-        run_start = jnp.searchsorted(sexp, sexp, side="left")
-        pos = jnp.arange(A) - run_start
-        keep = pos < C
-        buf = jnp.zeros((E, C, D), xr.dtype)
-        src = jnp.where(keep[:, None], xr[stok], 0)
-        buf = buf.at[jnp.where(keep, sexp, 0),
-                     jnp.where(keep, pos, 0)].add(src)
-        return buf, (sexp, stok, sgate, pos, keep)
+        tok = order // K                       # each sorted row's token
+        sizes = jnp.diff(jnp.searchsorted(
+            sexp, jnp.arange(E + 1, dtype=sexp.dtype))).astype(jnp.int32)
+        keep = jnp.ones((A,), bool)
+        if capacity_factor is not None:
+            # rank within (sequence, expert); sorted keys stay sorted
+            run = sexp * B + tok // S
+            rank = jnp.arange(A) - jnp.searchsorted(run, run, side="left")
+            keep = rank < max(1, math.ceil(S * K * capacity_factor / E))
+        xs = x.reshape(T, D)[tok]                               # (A, D)
 
-    buf, book = jax.vmap(dispatch_row)(x, gate_idx, gate_vals)  # (B,E,C,D)
-    buf = constrain_batch(buf)   # keep dispatch buffers batch-sharded
+    with jax.named_scope("moe_experts"):
+        if kind in ("swiglu", "geglu"):
+            act = jax.nn.silu if kind == "swiglu" else functools.partial(
+                jax.nn.gelu, approximate=True)
+            h = (act(jax.lax.ragged_dot(xs, params["wi_gate"], sizes))
+                 * jax.lax.ragged_dot(xs, params["wi_up"], sizes))
+        else:
+            h = jax.nn.gelu(jax.lax.ragged_dot(xs, params["wi_up"], sizes),
+                            approximate=True)
+        ys = jax.lax.ragged_dot(h, params["wo"], sizes)         # (A, D)
 
-    # ---- expert FFN: F is TP-sharded, D FSDP-sharded; all experts local ----
-    if kind in ("swiglu", "geglu"):
-        act = jax.nn.silu if kind == "swiglu" else (
-            lambda t: jax.nn.gelu(t, approximate=True))
-        h = (act(jnp.einsum("becd,edf->becf", buf,
-                            gather_fsdp(params["wi_gate"], tp_dim=2)))
-             * jnp.einsum("becd,edf->becf", buf,
-                          gather_fsdp(params["wi_up"], tp_dim=2)))
-    else:
-        h = jax.nn.gelu(jnp.einsum("becd,edf->becf", buf,
-                                   gather_fsdp(params["wi_up"], tp_dim=2)),
-                        approximate=True)
-    h = constrain_batch(h)
-    out_buf = constrain_batch(
-        jnp.einsum("becf,efd->becd", h,
-                   gather_fsdp(params["wo"], tp_dim=1)))        # (B,E,C,D)
+    with jax.named_scope("moe_combine"):
+        back = jnp.argsort(order)             # token order from sorted order
+        y = ys[back].reshape(B, S, K, D).astype(jnp.float32)
+        w = gates * keep[back].reshape(B, S, K)
+        out = jnp.einsum("bskd,bsk->bsd", y, w).astype(x.dtype)
 
-    def gather_row(obuf, bk):
-        sexp, stok, sgate, pos, keep = bk
-        vals = obuf[jnp.where(keep, sexp, 0), jnp.where(keep, pos, 0)]
-        vals = jnp.where(keep[:, None], vals, 0) * sgate[:, None].astype(
-            obuf.dtype)
-        return jnp.zeros((S, D), obuf.dtype).at[stok].add(vals)
-
-    out = constrain_batch(jax.vmap(gather_row)(out_buf, book))  # (B, S, D)
-    return out, {"aux_loss": aux_loss,
-                 "dropped_frac": 1.0 - jnp.mean(
-                     book[4].astype(jnp.float32))}
+    # the load-balance loss's two shares per expert: router probability,
+    # and tokens whose first choice it is
+    me = jax.nn.softmax(logits, axis=-1).mean(axis=(0, 1))
+    fe = jax.nn.one_hot(idx[..., 0], E, dtype=jnp.float32).mean(axis=(0, 1))
+    return out, {"me": me, "fe": fe,
+                 "dropped_frac": 1.0 - jnp.mean(keep.astype(jnp.float32))}

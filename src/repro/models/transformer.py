@@ -38,7 +38,8 @@ def _attn_cfg(cfg: ModelConfig, *, window=None, theta=None) -> L.AttnConfig:
         d_model=cfg.d_model, num_heads=cfg.num_heads,
         num_kv_heads=cfg.num_kv_heads, head_dim=cfg.hd,
         qkv_bias=cfg.qkv_bias, rope_theta=theta or cfg.rope_theta,
-        mrope=cfg.mrope, causal=True, window=window)
+        mrope=cfg.mrope, causal=True, window=window,
+        scale=cfg.attention_multiplier)
 
 
 def init_block(cfg: ModelConfig, key) -> tuple[dict, dict]:
@@ -89,6 +90,14 @@ def _mix(params, cfg, h):
     return m, {"aux_loss": jnp.zeros((), jnp.float32)}
 
 
+def _residual(cfg: ModelConfig, x, out):
+    """The residual add of a block's attention or MLP output, scaled by
+    the residual multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        out = out * cfg.residual_multiplier
+    return x + out
+
+
 def block_fwd(params, x, cfg: ModelConfig, positions, *,
               window=None, theta=None):
     """Full-sequence block.  Returns (x, (k, v), aux_loss)."""
@@ -96,9 +105,9 @@ def block_fwd(params, x, cfg: ModelConfig, positions, *,
     acfg = _attn_cfg(cfg, window=window, theta=theta)
     a, kv = L.attention_fwd(params["attn"], _norm1(params, cfg, x), acfg,
                             positions)
-    x = x + a
+    x = _residual(cfg, x, a)
     m, aux = _mix(params, cfg, _norm2(params, cfg, x))
-    return x + m, kv, aux["aux_loss"]
+    return _residual(cfg, x, m), kv, aux["aux_loss"]
 
 
 def block_decode(params, x, cfg: ModelConfig, k_cache, v_cache, kv_len,
@@ -111,9 +120,9 @@ def block_decode(params, x, cfg: ModelConfig, k_cache, v_cache, kv_len,
     a, k_new, v_new = L.attention_decode(
         params["attn"], _norm1(params, cfg, x), acfg, k_cache, v_cache,
         kv_len, positions)
-    x = x + a
+    x = _residual(cfg, x, a)
     m, _ = _mix(params, cfg, _norm2(params, cfg, x))
-    return x + m, k_new, v_new
+    return _residual(cfg, x, m), k_new, v_new
 
 
 # ---------------------------------------------------------------------------
@@ -160,14 +169,18 @@ def _final(params, cfg, x):
     with jax.named_scope("norm"):
         x = L.rms_norm(constrain_batch(x), params["final_norm"])
     with jax.named_scope("logits"):
-        return constrain_logits(L.unembed_fwd(params["embedding"], x))
+        return constrain_logits(L.unembed_fwd(params["embedding"], x,
+                                              cfg.logits_scaling))
 
 
-def _embed(params, tokens, embeds=None):
-    """Token embeddings; ``embeds`` (B, V, d_model), where given, override
-    the first V positions (VLM patch / audio frame stub)."""
+def _embed(params, cfg, tokens, embeds=None):
+    """Token embeddings, scaled by the embedding multiplier; ``embeds`` (B,
+    V, d_model), where given, override the first V positions (VLM patch /
+    audio frame stub)."""
     with jax.named_scope("embed"):
         x = L.embed_fwd(params["embedding"], tokens)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         if embeds is not None:
             V = embeds.shape[1]
             x = jnp.concatenate([embeds.astype(x.dtype), x[:, V:]], axis=1)
@@ -193,7 +206,7 @@ def lm_forward(params, cfg: ModelConfig, tokens, embeds=None,
     embeddings (VLM patch / audio frame stub) overriding the first V slots.
     Returns (logits, aux_loss)."""
     B, S = tokens.shape
-    x = _embed(params, tokens, embeds)
+    x = _embed(params, cfg, tokens, embeds)
     pos = _positions(cfg, B, S)
 
     if cfg.attention == "local_global":
@@ -284,7 +297,7 @@ def lm_decode_step(params, cfg: ModelConfig, cache: dict, kv_len, token,
     puts them at ``kv_len``.  With the cache donated, that write is in
     place and the step copies no cache."""
     B = token.shape[0]
-    x = _embed(params, token)
+    x = _embed(params, cfg, token)
     pos = _positions(cfg, B, 1, offset=kv_len)
 
     if cfg.attention == "local_global":
@@ -354,7 +367,7 @@ def lm_prefill(params, cfg: ModelConfig, tokens, cache_len: int | None = None,
     """
     B, S = tokens.shape
     cache_len = cache_len or S
-    x = _embed(params, tokens, embeds)
+    x = _embed(params, cfg, tokens, embeds)
     pos = _positions(cfg, B, S)
 
     if cfg.attention == "local_global":
@@ -449,7 +462,7 @@ def lm_decode_step_paged(params, cfg: ModelConfig, cache: dict, kv_len,
     B = token.shape[0]
     page = cache["page"]
     table = cache["block_table"]
-    x = _embed(params, token)
+    x = _embed(params, cfg, token)
     pos = _positions(cfg, B, 1, offset=kv_len)
     acfg = _attn_cfg(cfg)
     slot_page = kv_len // page
@@ -469,12 +482,13 @@ def lm_decode_step_paged(params, cfg: ModelConfig, cache: dict, kv_len,
                 v_new[:, 0].astype(v_pool.dtype))
         with jax.named_scope("attn_core"):
             seq_lens = jnp.full((B,), kv_len + 1, jnp.int32)
-            o = paged_attention(q[:, 0], k_pool, v_pool, table, seq_lens)
+            o = paged_attention(q[:, 0], k_pool, v_pool, table, seq_lens,
+                                scale=acfg.scale)
         with jax.named_scope("attn_out"):
             o = o.reshape(B, 1, cfg.num_heads * cfg.hd)
-            x = x + o @ blk["attn"]["wo"]
+            x = _residual(cfg, x, o @ blk["attn"]["wo"])
         m, _ = _mix(blk, cfg, _norm2(blk, cfg, x))
-        return x + m, (k_pool, v_pool)
+        return _residual(cfg, x, m), (k_pool, v_pool)
 
     with jax.named_scope("layers"):
         x, (k_pool, v_pool) = jax.lax.scan(

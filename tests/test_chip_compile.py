@@ -2,9 +2,10 @@
 
 The TPU compiler refuses what interpret mode accepts: unaligned slices,
 primitives Mosaic cannot lower, kernels over the VMEM budget, programs over
-the device's memory.  These tests run the compiler on the kernels and on the
-StarCoder2-7B decode and train steps at published widths, so such faults
-show up without chip time.  Nothing executes, so results are not checked
+the device's memory.  These tests run the compiler on the kernels, on the
+StarCoder2-7B decode and train steps and on Granite-3.0-3B-A800M's expert
+layer and decode at published widths, so such faults show up without chip
+time.  Nothing executes, so results are not checked
 here.
 
 The topology is described inside a fixture, never at import: only one
@@ -14,6 +15,7 @@ this file.
 
 import dataclasses
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +28,7 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.paged_attention.kernel import paged_attention_pallas
 from repro.kernels.ssm_scan.kernel import gla_scan_pallas
 from repro.launch.mesh import make_mesh
+from repro.models import moe
 from repro.models import transformer as TF
 from repro.models.registry import build_model
 from repro.optim import AdamWState
@@ -36,6 +39,10 @@ from test_decode_in_place import (KEEP, aliases, entry_name, in_place_write,
 
 HBM_BYTES = 16 * 1024 ** 3          # one v5e chip
 SC2 = get_config("starcoder2_7b")    # Hq 36, Hkv 4, head dim 128
+GRANITE = dataclasses.replace(         # the published multipliers, dropless
+    get_config("granite_moe_3b_a800m"), embedding_multiplier=12.0,
+    attention_multiplier=0.015625, residual_multiplier=0.22,
+    logits_scaling=6.0, capacity_factor=None)
 
 
 @pytest.fixture(scope="module")
@@ -202,3 +209,58 @@ def test_serving_decode_keeps_its_cache_in_place_at_16_layers(topo):
     made = [i for i in comps[entry_name(text)]
             if i["dims"] == cache["k"].shape and i["op"] not in KEEP]
     assert len(made) == 2 and all(in_place_write(comps, i) for i in made)
+
+
+def _grouped_matmuls(text: str) -> int:
+    return len([line for line in text.split("\n")
+                if 'custom_call_target="tpu_custom_call"' in line
+                and re.match(r"\s*(ROOT )?%ragged-dot-none", line)])
+
+
+def _dense_over_experts(text: str, tokens, cfg) -> list[str]:
+    """Arrays that hold a row for every token and every expert at a
+    width: what a grouped matmul expanded densely would make."""
+    widths = {cfg.d_model, cfg.d_ff}
+    return [i["name"] for comp in parse_hlo(text).values() for i in comp
+            if cfg.num_experts in i["dims"] and widths & set(i["dims"])
+            and set(tokens) & set(i["dims"])]
+
+
+def test_granite_expert_layer_and_decode_compile_to_grouped_matmuls(
+        topo, one_chip):
+    """The expert layer over a prefill of 16 x 1020 tokens and
+    ``make_serve_fns``' decode of 16 slots over a 2048-entry cache: the
+    gate, up and down projections are three grouped-matmul
+    kernels, no array spans tokens x experts x a width, and the donated
+    cache aliases the decode's output."""
+    cfg, B, S, CACHE = GRANITE, 16, 1020, 2048
+    E, K, D, F = cfg.num_experts, cfg.top_k, cfg.d_model, cfg.d_ff
+    params = {"router": _sds(one_chip, (D, E)),
+              "wi_gate": _sds(one_chip, (E, D, F)),
+              "wi_up": _sds(one_chip, (E, D, F)),
+              "wo": _sds(one_chip, (E, F, D))}
+    layer = jax.jit(lambda p, x: moe.moe_fwd(
+        p, x, num_experts=E, top_k=K, capacity_factor=None)[0])
+    text = layer.lower(params, _sds(one_chip, (B, S, D))).compile().as_text()
+    assert _grouped_matmuls(text) == 3
+    assert _dense_over_experts(text, (S, B * S, B * S * K), cfg) == []
+
+    api = build_model(cfg)
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    pshapes, axes = abstract_init(api)
+    _, decode_jit = make_serve_fns(
+        api, mesh, axes, ShapeConfig("granite", "prefill", S, B), pshapes)
+    batch = {"tokens": jax.ShapeDtypeStruct((B, S), jnp.int32)}
+    cache = jax.eval_shape(
+        lambda p, b: api.prefill(p, b, cache_len=CACHE), pshapes, batch)[1]
+    step = (jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1), jnp.int32))
+    with mesh, sh.activation_sharding_scope(mesh, "decode"):
+        compiled = decode_jit(cache).lower(pshapes, cache, *step).compile()
+    text = compiled.as_text()
+    assert _grouped_matmuls(text) == 3
+    assert _dense_over_experts(text, (B, B * K), cfg) == []
+    n_weights = len(jax.tree_util.tree_leaves(pshapes))
+    assert aliases(text) == {1: n_weights, 2: n_weights + 1}
+    cache_bytes = 2 * cache["k"].size * cache["k"].dtype.itemsize
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes

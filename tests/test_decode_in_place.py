@@ -238,7 +238,7 @@ def old_decode_step(params, cfg, cache, kv_len, token):
     the scan carries each layer's (B, S, KV, hd) cache through the old
     attention and stacks the written caches back."""
     B = token.shape[0]
-    x = TF._embed(params, token)
+    x = TF._embed(params, cfg, token)
     pos = TF._positions(cfg, B, 1, offset=kv_len)
     acfg = TF._attn_cfg(cfg)
 
