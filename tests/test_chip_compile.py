@@ -14,8 +14,11 @@ this file.
 """
 
 import dataclasses
+import json
 import math
 import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +39,10 @@ from repro.serve.engine import make_serve_fns
 from repro.train.loop import TrainConfig, abstract_init, make_train_fn
 from test_decode_in_place import (KEEP, aliases, entry_name, in_place_write,
                                   parse_hlo, while_bodies)
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+from benchmarks.chip import counts, moe_scopes  # noqa: E402
 
 HBM_BYTES = 16 * 1024 ** 3          # one v5e chip
 SC2 = get_config("starcoder2_7b")    # Hq 36, Hkv 4, head dim 128
@@ -226,14 +233,12 @@ def _dense_over_experts(text: str, tokens, cfg) -> list[str]:
             and set(tokens) & set(i["dims"])]
 
 
-def test_granite_expert_layer_and_decode_compile_to_grouped_matmuls(
+def test_granite_expert_layer_compiles_to_grouped_matmuls_in_prefill(
         topo, one_chip):
-    """The expert layer over a prefill of 16 x 1020 tokens and
-    ``make_serve_fns``' decode of 16 slots over a 2048-entry cache: the
-    gate, up and down projections are three grouped-matmul
-    kernels, no array spans tokens x experts x a width, and the donated
-    cache aliases the decode's output."""
-    cfg, B, S, CACHE = GRANITE, 16, 1020, 2048
+    """The expert layer over a prefill of 16 x 1020 tokens: the gate, up
+    and down projections are three grouped-matmul kernels, and no array
+    spans tokens x experts x a width."""
+    cfg, B, S = GRANITE, 16, 1020
     E, K, D, F = cfg.num_experts, cfg.top_k, cfg.d_model, cfg.d_ff
     params = {"router": _sds(one_chip, (D, E)),
               "wi_gate": _sds(one_chip, (E, D, F)),
@@ -245,6 +250,27 @@ def test_granite_expert_layer_and_decode_compile_to_grouped_matmuls(
     assert _grouped_matmuls(text) == 3
     assert _dense_over_experts(text, (S, B * S, B * S * K), cfg) == []
 
+
+def _stack_readers(text: str, stacks) -> list[str]:
+    """The top-level instructions of the loop bodies that take an operand
+    of one of the shapes ``stacks``."""
+    comps, readers = parse_hlo(text), []
+    for body in while_bodies(text):
+        holders = {i["name"] for i in comps[body] if i["dims"] in stacks}
+        readers += [i["name"] for i in comps[body]
+                    if i["op"] not in KEEP and holders & set(i["operands"])]
+    return readers
+
+
+def test_granite_decode_reads_the_expert_weights_in_place(topo):
+    """``make_serve_fns``' decode of 16 slots over a 2048-entry cache runs
+    each layer's experts as batched dots that read the scan's stacks of
+    expert weights where they lie: no grouped-matmul kernel, no operation
+    in the loop makes an array of one layer's expert weights (no copy), the
+    fusions that read the stacks are the expert layer's ``moe_experts``
+    (none ``moe_weights``, the benchmark's label for a copy), and the
+    donated cache aliases the output."""
+    cfg, B, S, CACHE = GRANITE, 16, 1020, 2048
     api = build_model(cfg)
     mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
     pshapes, axes = abstract_init(api)
@@ -258,8 +284,20 @@ def test_granite_expert_layer_and_decode_compile_to_grouped_matmuls(
     with mesh, sh.activation_sharding_scope(mesh, "decode"):
         compiled = decode_jit(cache).lower(pshapes, cache, *step).compile()
     text = compiled.as_text()
-    assert _grouped_matmuls(text) == 3
-    assert _dense_over_experts(text, (B, B * K), cfg) == []
+    assert _grouped_matmuls(text) == 0 and "ragged-dot" not in text
+
+    weights = moe_scopes.weight_shapes(counts.Dims.from_config(json.loads(
+        (ROOT / "benchmarks/chip/configs/granite-3.0-3b-a800m.json")
+        .read_text())))
+    comps = parse_hlo(text)
+    copies = [i["name"] for b in while_bodies(text) for i in comps[b]
+              if i["op"] not in KEEP and i["dims"] in weights]
+    assert copies == []
+    readers = _stack_readers(
+        text, {(cfg.num_layers,) + w for w in weights})
+    labels = moe_scopes.op_labels(text, weights)
+    assert readers and {labels[r] for r in readers} == {"moe_experts"}
+
     n_weights = len(jax.tree_util.tree_leaves(pshapes))
     assert aliases(text) == {1: n_weights, 2: n_weights + 1}
     cache_bytes = 2 * cache["k"].size * cache["k"].dtype.itemsize

@@ -63,7 +63,7 @@ INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* "
 
 def parse_hlo(text: str) -> dict[str, list[dict]]:
     """Computation name -> its instructions whose result is an array:
-    name, dims, opcode, and the computations they call."""
+    name, dims, opcode, operands, and the computations they call."""
     comps: dict[str, list[dict]] = {}
     current = None
     for line in text.split("\n"):
@@ -78,6 +78,8 @@ def parse_hlo(text: str) -> dict[str, list[dict]]:
                             "dims": tuple(int(d) for d in m.group(3).split(",")
                                           if d),
                             "op": m.group(4), "calls": calls,
+                            "operands": re.findall(
+                                r"%([\w.\-]+)", m.group(5).split(")")[0]),
                             "root": line.lstrip().startswith("ROOT ")})
     return comps
 

@@ -226,6 +226,43 @@ def test_capacity_factor_keeps_the_old_capacity_layer(capacity_factor):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("capacity_factor", [None, 1.25])
+@pytest.mark.parametrize("batch", [1, 16])
+def test_a_decode_step_gives_the_grouped_layers_result(monkeypatch, batch,
+                                                       capacity_factor):
+    """At S == 1 the batched dots over every expert give what the sorted,
+    grouped layer gives on the same tokens, its loss, and drop nothing.  In
+    float32, so that the two orders of summation agree to 1e-5."""
+    _, params = params_for(2)
+    mp, h = layer0_input(params, tokens_for(2))
+    mp = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), mp)
+    h = h.astype(jnp.float32).reshape(-1, 1, CFG.d_model)[:batch]
+    kw = dict(num_experts=CFG.num_experts, top_k=CFG.top_k,
+              capacity_factor=capacity_factor)
+    got, aux = moe.moe_fwd(mp, h, **kw)
+    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 0)     # the grouped layer
+    want, want_aux = moe.moe_fwd(mp, h, **kw)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    assert float(aux["aux_loss"]) == pytest.approx(
+        float(want_aux["aux_loss"]), rel=1e-6)
+    assert float(aux["dropped_frac"]) == float(want_aux["dropped_frac"]) == 0
+
+
+@pytest.mark.parametrize("batch,seq,grouped", [
+    (16, 1, 0), (128, 1, 0), (129, 1, 3), (4, 12, 3)])
+def test_only_a_decode_step_of_few_tokens_leaves_the_grouped_matmuls(
+        batch, seq, grouped):
+    E, D, F = CFG.num_experts, CFG.d_model, CFG.d_ff
+    shapes = {"router": (D, E), "wi_gate": (E, D, F), "wi_up": (E, D, F),
+              "wo": (E, F, D)}
+    mp = {k: jax.ShapeDtypeStruct(v, jnp.bfloat16) for k, v in shapes.items()}
+    x = jax.ShapeDtypeStruct((batch, seq, D), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda p, x: moe.moe_fwd(
+        p, x, num_experts=E, top_k=CFG.top_k, capacity_factor=None))(mp, x)
+    prims = [e.primitive.name for e in jaxpr.eqns]
+    assert sum(n.startswith("ragged_dot") for n in prims) == grouped
+
+
 def test_expert_scopes_reach_the_compiled_steps():
     api = build_model(CFG)
     mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
