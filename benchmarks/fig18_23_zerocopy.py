@@ -17,7 +17,8 @@ from __future__ import annotations
 import time
 
 from benchmarks.common import emit, section
-from repro.core.dds_server import DDSClient, DDSStorageServer, ServerConfig
+from repro.core.client import DDSClient
+from repro.core.dds_server import DDSStorageServer, ServerConfig
 from repro.core.file_service import FileServiceRunner, SegmentFS
 from repro.core.host_lib import DDSFrontEnd
 from repro.core.ring import DMAEngine
@@ -67,7 +68,7 @@ def _offload_rate(zero_copy: bool, size: int) -> tuple[float, int]:
     # drain the rest
     for _ in range(200_000):
         srv.pump()
-        cli.collect()
+        cli.poll()
         if srv.offload.stats.completed + srv.offload.stats.failed >= N_OPS:
             break
     dt = time.perf_counter() - t0

@@ -18,7 +18,7 @@ from __future__ import annotations
 import time
 
 from benchmarks.common import emit, section
-from repro.core.dds_server import DDSClient, encode_batch
+from repro.core.client import DDSClient
 from repro.storage.pagestore import KVStoreServer, PageStore
 
 N_PAGES = 64
@@ -31,9 +31,7 @@ def page_server() -> None:
         ps.replay(p, lsn=100, payload=f"page-{p}".encode())
     cli = DDSClient(ps.server)
     t0 = time.perf_counter()
-    rid = 0
     for i in range(N_GETS):
-        rid += 1
         if i % 10 == 0:
             # 10%: LSN newer than the cache -> host path (partial offload);
             # dedicated page range, since the host read invalidates the page
@@ -41,8 +39,8 @@ def page_server() -> None:
             page, lsn = N_PAGES - 1 - (i // 10) % 8, 150
         else:
             page, lsn = (i * 13) % (N_PAGES - 8), 100
-        cli._send(encode_batch([PageStore.encode_get(rid, page, lsn)]))
-        cli.wait(rid)
+        cli.wait(cli.send_raw(
+            0, lambda rid: PageStore.encode_get(rid, page, lsn)))
     dt = time.perf_counter() - t0
     st = ps.server.offload.stats
     emit("fig24_pageserver", dt / N_GETS * 1e6,
@@ -60,12 +58,10 @@ def kv_server() -> None:
         kv.upsert(f"hot{i}".encode(), b"tail")   # 16 host-resident keys
     cli = DDSClient(kv.server)
     t0 = time.perf_counter()
-    rid = 0
     for i in range(N_GETS):
-        rid += 1
         key = (f"hot{i % 16}" if i % 16 == 0 else f"k{(i * 7) % 256}").encode()
-        cli._send(encode_batch([KVStoreServer.encode_get(rid, key)]))
-        cli.wait(rid)
+        cli.wait(cli.send_raw(
+            0, lambda rid: KVStoreServer.encode_get(rid, key)))
     dt = time.perf_counter() - t0
     st = kv.server.offload.stats
     emit("fig25_26_kv", dt / N_GETS * 1e6,

@@ -116,11 +116,7 @@ def run_workload(cfg: dict) -> dict:
             per_client[(issued + k) % len(clients)].append((f, off, rsize))
         issued += cfg["reads_per_round"]
         for cli, reads in zip(clients, per_client):
-            if hasattr(cli, "read_many"):          # post-overhaul burst API
-                cli.read_many(reads)
-            else:                                  # pre-PR client: per-call
-                for f, off, n in reads:
-                    cli.read(f, off, n)
+            cli.submit([("r",) + r for r in reads])
         for cli in clients:
             cli.flush()
         if poll_style:

@@ -197,9 +197,9 @@ def run_workload(cfg: dict) -> dict:
             w_end = (ci + 1) * chunk_w if ci < nc - 1 else len(writes)
             rr = reads[ci * chunk_r : r_end]
             ww = writes[ci * chunk_w : w_end]
-            for rid in cli.read_many(rr):
+            for rid in cli.submit([("r",) + r for r in rr]):
                 issued[(ci, rid)] = (stamp, "get")
-            for rid in cli.write_many(ww):
+            for rid in cli.submit([("w",) + w for w in ww]):
                 issued[(ci, rid)] = (stamp, "write")
             if rec:
                 n_reads += len(rr)

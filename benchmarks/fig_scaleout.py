@@ -102,19 +102,11 @@ def calibrate(iters: int = 200_000) -> float:
 
 
 def _issue_gets(cli: KVClient, keys: list) -> None:
-    if hasattr(cli, "get_many"):       # post-overhaul burst API
-        cli.get_many(keys)
-    else:                              # pre-PR client: per-op calls
-        for k in keys:
-            cli.get(k)
+    cli.submit([("get", k) for k in keys])
 
 
 def _issue_puts(cli: KVClient, items: list) -> None:
-    if hasattr(cli, "put_many"):
-        cli.put_many(items)
-    else:
-        for k, v in items:
-            cli.put(k, v)
+    cli.submit([("put", k, v) for k, v in items])
 
 
 def _settle(clients: list) -> None:

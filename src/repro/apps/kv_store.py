@@ -831,9 +831,10 @@ class KVClient:
     ``tenant`` binds once per client; every shard connection underneath
     carries it, so the servers' QoS layer (fair demux, admission, per-
     tenant stats) attributes all of this client's traffic without any
-    per-call tenant argument.  The unified burst surface is
-    :meth:`submit` / :meth:`harvest`; ``get_many``/``put_many``/
-    ``delete_many`` remain as thin deprecated wrappers.
+    per-call tenant argument.  The burst surface is :meth:`submit` /
+    :meth:`harvest`; ``put``/``get``/``delete`` are its one-op forms.
+    Request tracking (replay, timeouts, redirects, shed retry) is the
+    :class:`ClusterClient` it holds.
     """
 
     def __init__(self, store: ShardedKVStore, ip: str = "10.0.0.9",
@@ -869,27 +870,20 @@ class KVClient:
         return shard
 
     def put(self, key: bytes, value: bytes) -> int:
-        return self.net.send_raw(self._shard(key),
-                                 lambda rid: encode_put(rid, key, value),
-                                 cls="w", key=key)
+        return self.submit([("put", key, value)])[0]
 
     def get(self, key: bytes) -> int:
-        return self.net.send_raw(self._shard(key),
-                                 lambda rid: encode_get(rid, key), key=key)
+        return self.submit([("get", key)])[0]
 
     def delete(self, key: bytes) -> int:
-        return self.net.send_raw(self._shard(key),
-                                 lambda rid: encode_del(rid, key),
-                                 cls="w", key=key)
+        return self.submit([("delete", key)])[0]
 
-    # -- unified burst surface --------------------------------------------------------
+    # -- burst surface ----------------------------------------------------------------
     def submit(self, ops: list[tuple]) -> list[int]:
         """Issue a burst of KV operations; one handle (request id) per op,
         in order.  Ops are ``("get", key)``, ``("put", key, value)`` or
         ``("delete", key)`` and mix freely in one batch (one rid-range
-        reservation, one flush round).  Harvest with :meth:`harvest`;
-        ``get_many``/``put_many``/``delete_many`` are thin deprecated
-        wrappers over this."""
+        reservation, one flush round).  Harvest with :meth:`harvest`."""
         shard = self._shard
         shards = [shard(op[1]) for op in ops]
         cls = [_KV_CLS[op[0]] for op in ops]
@@ -913,28 +907,6 @@ class KVClient:
         ``(wire.E_SHED, hint)``; typed decoding stays with ``wait_put`` /
         ``wait_value``."""
         return self.net.harvest(handles, block=block, max_iters=max_iters)
-
-    def _send_many(self, keys: list, encode, cls: str = "r") -> list[int]:
-        shard = self._shard
-        return self.net.issue_many([shard(k) for k in keys],
-                                   lambda rid, i: encode(rid, keys[i]),
-                                   cls=cls, keys=keys)
-
-    def get_many(self, keys: list) -> list[int]:
-        """Deprecated: ``submit([("get", k), ...])``."""
-        return self._send_many(keys, encode_get)
-
-    def delete_many(self, keys: list) -> list[int]:
-        """Deprecated: ``submit([("delete", k), ...])``."""
-        return self._send_many(keys, encode_del, cls="w")
-
-    def put_many(self, items: list) -> list[int]:
-        """Deprecated: ``submit([("put", k, v), ...])``."""
-        shard = self._shard
-        return self.net.issue_many(
-            [shard(k) for k, _ in items],
-            lambda rid, i: encode_put(rid, items[i][0], items[i][1]),
-            cls="w", keys=[k for k, _ in items])
 
     # -- scheduling + typed waits -----------------------------------------------------
     @property

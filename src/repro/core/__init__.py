@@ -8,13 +8,14 @@ Layers (paper section in parens):
   cache_table   cuckoo-hash cache table (§6.1)
   traffic       bump-in-the-wire traffic director + PEP splitting (§5)
   offload       offload engine: OffPred/OffFunc/Cache/Invalidate (§6)
-  dds_server    the assembled storage server + benchmark client (§8.1)
+  dds_server    the assembled storage server + benchmark protocol (§8.1)
+  client        the storage clients: one transport, one request book
   simulate      calibrated event model for DPU-hardware figures (§8)
 """
 
 from repro.core.cache_table import CacheTable
-from repro.core.client import ClusterClient, ShardConnection
-from repro.core.dds_server import DDSClient, DDSStorageServer, ServerConfig
+from repro.core.client import ClusterClient, DDSClient, ShardConnection
+from repro.core.dds_server import DDSStorageServer, ServerConfig
 from repro.core.file_service import FileServiceRunner, SegmentFS
 from repro.core.host_lib import DDSFrontEnd
 from repro.core.offload import OffloadAPI, OffloadEngine, ReadOp, WriteOp

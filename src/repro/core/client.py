@@ -1,37 +1,52 @@
-"""Cluster client: per-shard batching + pipelining over the §8.1 wire format.
+"""Storage clients: one transport, one request book, two routings.
 
-The single-server :class:`~repro.core.dds_server.DDSClient` sends one network
-message per call and blocks in ``wait``.  Serving heavy traffic needs the two
-client-side techniques the paper's benchmark driver uses (§8.1):
+* :class:`ShardConnection` is THE transport: it opens one PEP-terminated
+  flow to one server (the SYN), numbers and checksum-stamps every packet,
+  and drains that flow's responses.
+* :class:`RequestBook` is THE request tracking both clients share: rid
+  allocation and outstanding counts, issue-tick stamps and end-to-end
+  latency, replay notes, tick timeouts with doubling backoff, epoch-fence
+  redirect replay (capped per rid), terminal ``E_SHED`` resolution, shed
+  retry with jittered backoff, and the duplicate-response drop.
+* :class:`DDSClient` is the book over ONE connection to one
+  :class:`DDSStorageServer`; it sends at once (every issue call flushes).
+* :class:`ClusterClient` is the book over one connection per shard of a
+  :class:`DDSCluster`, routed through the cluster's placement, with the two
+  client-side techniques of the paper's benchmark driver (§8.1):
 
-  * **batching** — requests destined for the same shard are packed into one
-    network message (``encode_batch``), so the traffic director runs its
-    signature + predicate once per batch, not once per request;
-  * **pipelining** — the client keeps issuing batches without waiting for
-    responses; each shard's offload engine preserves per-connection request
-    order (Fig 13), so responses stream back in issue order per shard.
+    - **batching** — requests destined for the same shard are packed into
+      one network message (``encode_batch``), so the traffic director runs
+      its signature + predicate once per batch, not once per request;
+    - **pipelining** — the client keeps issuing batches without waiting for
+      responses; each shard's offload engine preserves per-connection
+      request order (Fig 13), so responses stream back in issue order.
 
+The burst surface of every client is ``submit(ops)`` / ``harvest``; the
+one-op calls (``read``, ``write``, ``send_raw``) are forms of it.
 Everything is cooperatively scheduled: ``pump()`` flushes pending batches,
-steps every shard, and drains responses — tests can single-step the whole
-cluster deterministically.
+steps the server(s), and drains responses — tests can single-step the
+whole system deterministically.
 """
 
 from __future__ import annotations
 
 import threading
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import TYPE_CHECKING, Callable
 
 from repro.core import wire
-from repro.core.dds_server import (_OP_KIND, DDSStorageServer,
-                                   drain_client_flow, encode_app_read,
-                                   encode_app_write, encode_batch)
+from repro.core.dds_server import (DDSStorageServer, drain_client_flow,
+                                   encode_app_read, encode_app_write,
+                                   encode_batch)
 from repro.core.lifecycle import ClientLatency
 from repro.core.traffic import FLAG_SYN, FiveTuple, Packet
 from repro.core.vector import checksum64, scalar_mix
 
 if TYPE_CHECKING:  # import cycle: distributed.cluster imports core
     from repro.distributed.cluster import DDSCluster
+
+# ``submit`` op spellings -> the latency/replay class ("r"/"w").
+_OP_KIND = {"r": "r", "read": "r", "w": "w", "write": "w"}
 
 
 @dataclass
@@ -46,7 +61,7 @@ class ClientStats:
 
 
 class ShardConnection:
-    """One PEP-terminated flow to one shard (non-blocking enqueue/flush)."""
+    """One PEP-terminated flow to one server (non-blocking enqueue/flush)."""
 
     def __init__(self, server: DDSStorageServer, ip: str, port: int,
                  tenant: int = 0):
@@ -80,69 +95,46 @@ class ShardConnection:
             pkt.csum = checksum64(payload)
         self.server.director.ingress.push(pkt)
         self._seq += len(payload)
-        self.server.signal()   # client send: mark the target shard runnable
+        self.server.signal()   # client send: mark the target server runnable
         return n
 
     def collect(self, responses: dict[int, tuple[int, bytes]]) -> int:
         """Drain OUR flow's packets; reassemble the segmented response stream.
 
         The director's ``to_client`` wire is demuxed per flow, so this is an
-        O(1) swap of our own queue — other clients' traffic is never touched
-        (the old shared wire forced a pop-and-requeue scan past every other
-        client's packets on every drain)."""
+        O(1) swap of our own queue — other clients' traffic is never
+        touched."""
         return drain_client_flow(self.server.director, self._resp_flow,
                                  self._rx, responses, self.arrival_order)
 
 
-class ClusterClient:
-    """Batched, pipelined client for a :class:`DDSCluster`.
+class RequestBook:
+    """Request tracking over a list of :class:`ShardConnection` s.
 
-    Requests are routed by cluster-global file id through the cluster's
-    consistent-hash placement (``cluster.locate``); applications with their
-    own keys (e.g. the KV store) route via ``send_raw(shard, build_msg)``.
+    A subclass opens the connections, sets ``timeout_ticks`` (and, to shed
+    retry, ``retry_attempts``) and supplies the routing: ``_locate(fid)``
+    gives ``(shard, local fid)``, ``_route_of(shard)`` the live route of a
+    shard, ``_shard_for_key(key)`` a key's owner, ``_step()`` steps the
+    server(s), and ``_on_redirect(hint)`` adopts the epoch a fence refusal
+    names.  ``_dead`` holds shards that can never answer and ``_servers``
+    the servers whose busy devices an idle wait drains.
     """
 
-    _next_base_port = 40000          # distinct flows per client by default
-    _port_lock = threading.Lock()
+    retry_attempts = 0   # shed retries per rid: 0 = surface E_SHED at once
+    _armed = False       # epoch-tagged traffic that failover can re-route
+    _dead: set[int] | frozenset = frozenset()
 
-    def __init__(self, cluster: "DDSCluster", ip: str = "10.0.0.9",
-                 port: int | None = None, tenant: int = 0,
-                 retry_attempts: int = 0, timeout_ticks: int = 0):
-        self.cluster = cluster
-        self.tenant = tenant
-        self._ip = ip
-        if port is None:
-            # Each client needs its own source ports, or two clients' flows
-            # (and therefore their responses) become indistinguishable.
-            with ClusterClient._port_lock:
-                port = ClusterClient._next_base_port
-                ClusterClient._next_base_port += len(cluster.servers)
-        self.conns = [ShardConnection(srv, ip, port + i, tenant)
-                      for i, srv in enumerate(cluster.servers)]
-        # Failover/reshard awareness, armed on replicated or elastic
-        # clusters: packets are epoch-tagged, issued requests keep a replay
-        # note, and an epoch bump (failover promotion or resharding flip)
-        # transparently re-routes everything parked on the old owner.
-        # Plain clusters pay one attribute test per pump.
-        self._armed = cluster.supervisor is not None or cluster.elastic
-        self._epoch_seen = cluster.epoch
-        epoch = cluster.epoch if self._armed else -1
-        for conn in self.conns:
-            conn.epoch = epoch
-        # Shed retry (bounded exponential backoff honoring the server's
-        # ``retry_after`` hint): 0 = surface E_SHED to the caller directly.
-        self.retry_attempts = retry_attempts
+    def __init__(self, conns: list[ShardConnection], clock):
+        self.conns = conns
+        self.clock = clock
         # Lossy-wire recovery: a request unanswered for ``timeout_ticks``
         # is re-sent from its replay note with doubled backoff (the
-        # server-side dedup cache makes resends exactly-once).  0 = off.
-        self.timeout_ticks = timeout_ticks
+        # server-side dedup cache makes resends exactly-once).
         self._deadlines: dict[int, tuple[int, int]] = {}  # rid -> (due, attempt)
-        self._replay_on = (self._armed or retry_attempts > 0
-                           or timeout_ticks > 0)
-        # rid -> ("op", kind, gfid, offset, arg) for fid-addressed requests
-        # (MUST re-encode at replay: the promoted shard's adopted copy has a
-        # different local fid) or ("raw", shard, msg, cls) for application
-        # messages (key-addressed; the bytes stay valid on the new shard).
+        # rid -> ("op", kind, fid, offset, arg) for fid-addressed requests
+        # (MUST re-encode at replay: a promoted shard's adopted copy has a
+        # different local fid) or ("raw", shard, msg, cls, key) for
+        # application messages (the bytes stay valid on the new shard).
         self._replay: dict[int, tuple] = {}
         self._retries: dict[int, int] = {}        # rid -> shed retry count
         self._redirects_seen: dict[int, int] = {}  # rid -> redirect replays
@@ -154,22 +146,23 @@ class ClusterClient:
         # shards with outstanding requests, and ``flush`` visits only dirty
         # (buffered-but-unsent) connections — client-side mirrors of the
         # cluster's ready-set scheduling, so idle shards cost nothing.
-        self._shard_outstanding = [0] * len(self.conns)
+        self._shard_outstanding = [0] * len(conns)
         self._dirty: list[int] = []    # shard indices with pending messages
-        self._dirty_flag = [False] * len(self.conns)
+        self._dirty_flag = [False] * len(conns)
         self._lock = threading.Lock()
         self.responses: dict[int, tuple[int, bytes]] = {}
         self.stats = ClientStats()
         # End-to-end tick latency: issue stamps per rid (reads and writes
         # in separate dicts — the class is known at issue, so the drain
-        # pays one dict pop, no cross-object classification).  The
-        # offloaded-vs-host split for reads lives in the server-side
-        # lifecycle histograms, where it is exact.  The cluster's shared
-        # clock makes deltas comparable across shards.
+        # pays one dict pop).  The offloaded-vs-host split for reads lives
+        # in the server-side lifecycle histograms, where it is exact.
         self._issued_r: dict[int, int] = {}
         self._issued_w: dict[int, int] = {}
         self.latency = ClientLatency()
-        self._lat_pos = [0] * len(self.conns)  # arrival_order scan cursors
+        self._lat_pos = [0] * len(conns)  # arrival_order scan cursors
+
+    def _grow_conns(self) -> None:
+        """Open flows to servers added since construction (clusters)."""
 
     # -- request issue (buffered until the next flush/pump) -------------------------
     def _enqueue(self, shard: int, msg: bytes) -> None:
@@ -181,10 +174,8 @@ class ClusterClient:
     def reserve_rids(self, shards: list[int], cls="r") -> list[int]:
         """Reserve one rid per target shard in ONE lock round.
 
-        The shared bulk-issue path under :meth:`submit` and application
-        burst clients (e.g. the KV store's ``submit``): rid range,
-        outstanding counters and the rid->shard map are all updated in
-        bulk, so a pipeline round of thousands of requests skips the
+        Rid range, outstanding counters and the rid->shard map are updated
+        in bulk, so a pipeline round of thousands of requests skips the
         per-call lock + dict churn.  ``cls`` picks the issue-tick stamp
         class for the end-to-end latency histograms: either one 'r'/'w'
         for the whole burst, or a per-op sequence for mixed batches."""
@@ -205,7 +196,7 @@ class ClusterClient:
             for rid, shard in zip(rids, shards):
                 rid_shard[rid] = shard
                 outs[shard] += 1
-        now = self.cluster.clock.now
+        now = self.clock.now
         if isinstance(cls, str):
             issued = self._issued_r if cls == "r" else self._issued_w
             for rid in rids:
@@ -217,155 +208,55 @@ class ClusterClient:
         self.stats.requests += n
         return rids
 
-    def _grow_conns(self) -> None:
-        """The cluster grew (elastic ``add_shard``): open flows to the new
-        shards.  Ports come from the GLOBAL allocator — extending this
-        client's original contiguous block would collide with whichever
-        client allocated the next block."""
-        cl = self.cluster
-        n = len(cl.servers)
-        if n <= len(self.conns):
-            return
-        add = n - len(self.conns)
-        with ClusterClient._port_lock:
-            base = ClusterClient._next_base_port
-            ClusterClient._next_base_port += add
-        epoch = cl.epoch if self._armed else -1
-        for i in range(add):
-            conn = ShardConnection(cl.servers[len(self.conns)],
-                                   self._ip, base + i, self.tenant)
-            conn.epoch = epoch
-            self.conns.append(conn)
-            self._lat_pos.append(0)
-        with self._lock:
-            while len(self._shard_outstanding) < n:
-                self._shard_outstanding.append(0)
-            while len(self._dirty_flag) < n:
-                self._dirty_flag.append(False)
-
-    def _rid(self, shard: int, cls: str = "r") -> int:
-        if shard >= len(self.conns):
-            self._grow_conns()
-        with self._lock:
-            rid = self._next_rid
-            self._next_rid += 1
-            self._outstanding += 1
-            self._shard_outstanding[shard] += 1
-        self._rid_shard[rid] = shard
-        issued = self._issued_r if cls == "r" else self._issued_w
-        issued[rid] = self.cluster.clock.now
-        self.stats.requests += 1
-        return rid
-
     def _arm_timeout(self, rid: int) -> None:
         if self.timeout_ticks:
-            self._deadlines[rid] = (self.cluster.clock.now
-                                    + self.timeout_ticks, 0)
+            self._deadlines[rid] = (self.clock.now + self.timeout_ticks, 0)
 
-    def read(self, gfid: int, offset: int, nbytes: int) -> int:
-        loc = self.cluster.locate(gfid)
-        rid = self._rid(loc.shard)
-        if self._replay_on:
-            self._replay[rid] = ("op", "r", gfid, offset, nbytes)
-            self._arm_timeout(rid)
-        self._enqueue(loc.shard,
-                      encode_app_read(rid, loc.local_fid, offset, nbytes))
-        return rid
-
-    # -- unified burst surface --------------------------------------------------------
     def submit(self, ops: list[tuple]) -> list[int]:
         """Issue a burst of operations; returns one handle (request id) per
-        op, in order.  THE burst-issue surface — every legacy burst name
-        (``read_many``/``write_many``/``wait_many``) is a thin deprecated
-        wrapper over ``submit``/:meth:`harvest`.
+        op, in order.  Harvest results with :meth:`harvest`.
 
-        Ops are ``("r"|"read", gfid, offset, nbytes)`` or
-        ``("w"|"write", gfid, offset, data)``; reads and writes mix freely
-        in one batch (per-op latency classes ride the generalized
-        :meth:`reserve_rids`).  The client's tenant binds once per
-        connection and rides every flow — never passed per call.
-        """
-        locate = self.cluster.locate
+        Ops are ``("r"|"read", fid, offset, nbytes)`` or
+        ``("w"|"write", fid, offset, data)``; reads and writes mix freely
+        in one batch.  The client's tenant binds once per connection and
+        rides every flow — never passed per call."""
         locs = []
         cls = []
         for op in ops:
             cls.append(_OP_KIND[op[0]])
-            locs.append(locate(op[1]))
-        rids = self.reserve_rids([loc.shard for loc in locs], cls)
+            locs.append(self._locate(op[1]))
+        rids = self.reserve_rids([shard for shard, _ in locs], cls)
         enqueue = self._enqueue
         replay = self._replay if self._replay_on else None
-        for rid, loc, k, op in zip(rids, locs, cls, ops):
+        for rid, (shard, local), k, op in zip(rids, locs, cls, ops):
             if replay is not None:
                 replay[rid] = ("op", k, op[1], op[2], op[3])
                 self._arm_timeout(rid)
-            if k == "r":
-                enqueue(loc.shard,
-                        encode_app_read(rid, loc.local_fid, op[2], op[3]))
-            else:
-                enqueue(loc.shard,
-                        encode_app_write(rid, loc.local_fid, op[2], op[3]))
+            encode = encode_app_read if k == "r" else encode_app_write
+            enqueue(shard, encode(rid, local, op[2], op[3]))
         return rids
 
-    def read_many(self, reads: list[tuple[int, int, int]]) -> list[int]:
-        """Deprecated: ``submit([("r", gfid, off, n), ...])``."""
-        return self.submit([("r", gfid, offset, nbytes)
-                            for gfid, offset, nbytes in reads])
+    def read(self, fid: int, offset: int, nbytes: int) -> int:
+        return self.submit([("r", fid, offset, nbytes)])[0]
 
-    def write(self, gfid: int, offset: int, data: bytes) -> int:
-        loc = self.cluster.locate(gfid)
-        rid = self._rid(loc.shard, "w")
-        if self._replay_on:
-            self._replay[rid] = ("op", "w", gfid, offset, data)
-            self._arm_timeout(rid)
-        self._enqueue(loc.shard,
-                      encode_app_write(rid, loc.local_fid, offset, data))
-        return rid
-
-    def write_many(self, writes: list[tuple[int, int, bytes]]) -> list[int]:
-        """Deprecated: ``submit([("w", gfid, off, data), ...])``.
-
-        Writes to one shard keep issue order, which the coalescing file
-        service turns into adjacent scatter-gather runs."""
-        return self.submit([("w", gfid, offset, data)
-                            for gfid, offset, data in writes])
-
-    def send_raw(self, shard: int, build_msg: Callable[[int], bytes],
-                 cls: str = "r", key: bytes | None = None) -> int:
-        """Route an application-defined message to an explicit shard.
-
-        The shard is translated through the cluster's repair chain at
-        issue time: after a failover the ring owner's route moves to the
-        promoted replica and STAYS moved — even once the old primary
-        heals and rejoins as a replica, sending to it directly would
-        split-brain its application state.
-
-        ``key`` (optional) is kept on the replay note: a resharding flip
-        moves key OWNERSHIP without a failover, so a replay must re-hash
-        the key against the current ring rather than follow the repair
-        chain of the originally targeted shard."""
-        shard = self.cluster.route_of(shard)
-        rid = self._rid(shard, cls)
-        msg = build_msg(rid)
-        if self._replay_on:
-            self._replay[rid] = ("raw", shard, msg, cls, key)
-            self._arm_timeout(rid)
-        self._enqueue(shard, msg)
-        return rid
+    def write(self, fid: int, offset: int, data: bytes) -> int:
+        return self.submit([("w", fid, offset, data)])[0]
 
     def issue_many(self, shards: list[int],
                    build_msg: Callable[[int, int], bytes],
-                   cls: str = "r", keys: list | None = None) -> list[int]:
-        """Burst form of :meth:`send_raw`: the PUBLIC bulk-issue path for
-        application clients (e.g. the KV store's ``get_many``).
+                   cls="r", keys: list | None = None) -> list[int]:
+        """Issue application-defined messages to explicit shards: the raw
+        burst path (e.g. the KV store's ``submit``).
 
         ``build_msg(rid, i)`` encodes the i-th message with its reserved
-        request id.  One rid-range reservation covers the whole burst, and
-        enqueueing stays inside this class so the dirty-connection and
-        per-shard outstanding bookkeeping cannot be bypassed.  Target
-        shards follow the cluster's repair chain (see :meth:`send_raw`);
-        ``keys`` (optional, parallel to ``shards``) makes replays re-hash
-        each key against the current ring (resharding flips)."""
-        route_of = self.cluster.route_of
+        request id.  Target shards follow the live route
+        (``_route_of``): after a failover the ring owner's route moves to
+        the promoted replica and STAYS moved — even once the old primary
+        heals and rejoins as a replica, sending to it directly would
+        split-brain its application state.  ``keys`` (optional, parallel
+        to ``shards``) makes replays re-hash each key against the current
+        ring: a resharding flip moves key OWNERSHIP without a failover."""
+        route_of = self._route_of
         shards = [route_of(s) for s in shards]
         rids = self.reserve_rids(shards, cls)
         enqueue = self._enqueue
@@ -380,14 +271,19 @@ class ClusterClient:
             enqueue(shard, msg)
         return rids
 
+    def send_raw(self, shard: int, build_msg: Callable[[int], bytes],
+                 cls: str = "r", key: bytes | None = None) -> int:
+        """One-op form of :meth:`issue_many`: ``build_msg(rid)``."""
+        return self.issue_many([shard], lambda rid, _i: build_msg(rid), cls,
+                               None if key is None else [key])[0]
+
     # -- pipelined scheduling ---------------------------------------------------------
     def flush(self) -> int:
-        """Send one batched message per DIRTY shard with buffered requests.
+        """Send one batched message per DIRTY connection.
 
-        Only connections that actually buffered messages since the last
-        flush are visited (and their shards doorbell-signaled through
-        ``ShardConnection.flush``) — on a 16-shard cluster with skewed
-        traffic the old every-conn scan was pure idle cost."""
+        Only connections that buffered messages since the last flush are
+        visited (and their servers doorbell-signaled through
+        ``ShardConnection.flush``)."""
         if not self._dirty:
             return 0
         sent = 0
@@ -403,15 +299,10 @@ class ClusterClient:
         return sent
 
     def pump(self) -> int:
-        """One cooperative step: flush -> step every shard -> drain responses.
-
-        On replicated clusters the step also reconciles failovers (a ring
-        epoch bump re-routes and replays everything parked on the dead
-        shard) and releases shed retries whose backoff expired."""
+        """One cooperative step: flush -> step the server(s) -> release shed
+        retries and expired timeouts -> drain responses."""
         work = self.flush()
-        work += self.cluster.pump()
-        if self._armed:
-            work += self._sync_epoch()
+        work += self._step()
         if self._backoff:
             work += self._pump_backoff()
         if self._deadlines:
@@ -419,12 +310,12 @@ class ClusterClient:
         return work + self.poll()
 
     def poll(self) -> int:
-        """Drain THIS client's responses without stepping the cluster.
+        """Drain THIS client's responses without stepping any server.
 
-        Harvests ONLY shards with outstanding requests (the per-shard
+        Harvests ONLY connections with outstanding requests (the per-shard
         issued-minus-collected counters): with several clients sharing a
-        16-shard cluster, a client with traffic on two shards no longer
-        peeks the other fourteen demuxed queues on every scheduling round.
+        16-shard cluster, a client with traffic on two shards does not
+        peek the other fourteen demuxed queues on every scheduling round.
         """
         responses = self.responses
         got = 0
@@ -451,7 +342,7 @@ class ClusterClient:
                     if rid not in rid_shard and \
                             responses.pop(rid, None) is not None:
                         self.stats.dup_responses += 1
-                self._record_latency(conn, ao, lat_pos[i])
+                self._record_latency(ao, lat_pos[i])
                 if len(ao) >= 1 << 16:
                     # Fully consumed: reset so a long-running client's
                     # arrival log cannot grow without bound.
@@ -474,15 +365,11 @@ class ClusterClient:
             self.stats.responses += got
         return got
 
-    def _record_latency(self, conn: ShardConnection, arrival_order: list,
-                        pos: int) -> None:
-        """End-to-end issue->drain ticks for newly arrived responses.
-
-        Classified read/write from the issue-side stamp dicts (one pop on
-        the common path); the offloaded-vs-host split for reads is exact in
-        the serving shard's ``lifecycle`` histograms."""
+    def _record_latency(self, arrival_order: list, pos: int) -> None:
+        """End-to-end issue->drain ticks for newly arrived responses,
+        classified read/write from the issue-side stamp dicts."""
         latency = self.latency
-        now = self.cluster.clock.now
+        now = self.clock.now
         wpop = self._issued_w.pop
         rpop = self._issued_r.pop
         radd = latency.hist_for("read").add
@@ -498,9 +385,8 @@ class ClusterClient:
 
     def _any_terminal(self) -> bool:
         """True iff any connected server holds a terminal mark."""
-        conns = self.conns
         seen: set[int] = set()
-        for conn in (conns.values() if hasattr(conns, "values") else conns):
+        for conn in self.conns:
             lc = conn.server.lifecycle
             if id(lc) in seen:
                 continue
@@ -508,6 +394,15 @@ class ClusterClient:
             if lc.has_terminal():
                 return True
         return False
+
+    def _release(self, rid: int, shard: int) -> None:
+        """Unbook a rid that surfaced without a wire response."""
+        self._rid_shard.pop(rid, None)
+        self._issued_r.pop(rid, None)
+        self._issued_w.pop(rid, None)
+        with self._lock:
+            self._shard_outstanding[shard] -= 1
+            self._outstanding -= 1
 
     def _check_terminal(self, rids) -> int:
         """Reconcile terminal server-side marks for ``rids``.
@@ -522,17 +417,16 @@ class ClusterClient:
             under the bounded-backoff policy).
 
         ``E_REDIRECT``
-            Refused by the epoch fence: the request was routed before a
-            failover repaired the ring.  Replayed transparently against
-            the repaired ring with the SAME request id (bounded per rid);
-            surfaced terminally only past the cap or with no replay note.
+            Refused by the epoch fence: the request was routed before the
+            ring changed.  Replayed transparently under the adopted epoch
+            with the SAME request id (at most 8 times per rid); surfaced
+            terminally past the cap or with no replay note.
 
         Each mark is reconciled against ITS OWN shard's outstanding counter
         exactly once — the rid->shard entry is consumed on surfacing, so a
         rid can never be double-decremented (or charged against another
         tenant's connection) even if callers probe it again."""
         found = 0
-        responses = self.responses
         conns = self.conns
         rid_shard = self._rid_shard
         for rid in rids:
@@ -548,76 +442,50 @@ class ClusterClient:
                 seen = self._redirects_seen.get(rid, 0)
                 if rid in self._replay and seen < 8:
                     self._redirects_seen[rid] = seen + 1
-                    self._sync_epoch()
+                    self._on_redirect(hint)
                     if self._resubmit(rid):
                         found += 1
-                        continue
-                    continue  # _resubmit surfaced it terminally
-            responses[rid] = (code, hint)
-            rid_shard.pop(rid, None)
-            self._issued_r.pop(rid, None)
-            self._issued_w.pop(rid, None)
-            with self._lock:
-                self._shard_outstanding[shard] -= 1
-                self._outstanding -= 1
+                    continue  # else _resubmit surfaced it terminally
+            self.responses[rid] = (code, hint)
+            self._release(rid, shard)
             found += 1
         return found
 
-    # -- failover reconciliation -------------------------------------------------------
-    def _sync_epoch(self) -> int:
-        """Adopt the cluster's ring epoch after a failover.
-
-        Updates every connection's outgoing epoch tag and re-routes each
-        unanswered request parked on a now-dead shard — the dead shard can
-        never answer, so without this those rids would hang forever.
-        Returns the number of requests moved (work, for pump loops)."""
-        cur = self.cluster.epoch
-        if cur == self._epoch_seen:
-            return 0
-        self._epoch_seen = cur
-        self._grow_conns()   # an elastic add_shard bumps the epoch too
-        for conn in self.conns:
-            conn.epoch = cur
-        dead = self.cluster._dead
-        if not dead:
-            return 0
-        moved = 0
-        responses = self.responses
-        for rid, shard in list(self._rid_shard.items()):
-            if shard not in dead or rid in responses:
-                continue
-            if self._resubmit(rid):
-                moved += 1
-        return moved
-
     def _replay_msg(self, rid: int, entry: tuple) -> tuple[int, bytes]:
-        """Re-materialize a request against the CURRENT ring: fid-addressed
-        ops re-encode (the promoted shard's adopted copy has a different
-        local fid); raw application messages re-route by repaired shard."""
+        """Re-materialize a request against the CURRENT routing: fid-addressed
+        ops re-encode (a promoted shard's adopted copy has a different local
+        fid); raw application messages re-route."""
         if entry[0] == "op":
-            _, kind, gfid, offset, arg = entry
-            loc = self.cluster.locate(gfid)
-            if kind == "r":
-                return loc.shard, encode_app_read(rid, loc.local_fid,
-                                                  offset, arg)
-            return loc.shard, encode_app_write(rid, loc.local_fid,
-                                               offset, arg)
+            _, kind, fid, offset, arg = entry
+            shard, local = self._locate(fid)
+            encode = encode_app_read if kind == "r" else encode_app_write
+            return shard, encode(rid, local, offset, arg)
         _, shard, msg, _cls, key = entry
         if key is not None:
             # Key-addressed: ownership may have MOVED at a resharding
             # flip — re-hash against the current ring (the repair chain
             # only tracks failover promotions, not migrations).
-            return self.cluster.shard_for_key(key), msg
-        return self.cluster.route_of(shard), msg
+            return self._shard_for_key(key), msg
+        return self._route_of(shard), msg
+
+    def _move(self, rid: int, old: int, shard: int) -> None:
+        """Re-home a booked rid's per-shard count (the totals stay)."""
+        if shard != old:
+            with self._lock:
+                self._shard_outstanding[old] -= 1
+                self._shard_outstanding[shard] += 1
+            self._rid_shard[rid] = shard
 
     def _resubmit(self, rid: int) -> bool:
-        """Move a still-booked rid to its repaired shard and re-enqueue it.
+        """Move a still-booked rid to its current route and re-enqueue it.
 
         Counters stay booked (the request never surfaced); only the
         per-shard split moves.  A rid with no replay note — or whose
-        repaired route is itself dead (unrecoverable group) — is surfaced
-        terminally as ``(E_REDIRECT, current epoch)`` instead, so callers
-        see a retryable error rather than a hang."""
+        route is itself dead (unrecoverable group) — is surfaced
+        terminally as ``(E_REDIRECT, epoch)`` instead, so callers see a
+        retryable error rather than a hang.  Every caller adopts the
+        current epoch first, so the epoch the connections now stamp is
+        the one to report."""
         old = self._rid_shard.get(rid)
         if old is None:
             return False
@@ -625,21 +493,12 @@ class ClusterClient:
         shard = None
         if entry is not None:
             shard, msg = self._replay_msg(rid, entry)
-        if shard is None or shard in self.cluster._dead:
-            self.responses[rid] = (
-                wire.E_REDIRECT, wire.encode_redirect_hint(self.cluster.epoch))
-            self._rid_shard.pop(rid, None)
-            self._issued_r.pop(rid, None)
-            self._issued_w.pop(rid, None)
-            with self._lock:
-                self._shard_outstanding[old] -= 1
-                self._outstanding -= 1
+        if shard is None or shard in self._dead:
+            hint = wire.encode_redirect_hint(self.conns[0].epoch)
+            self.responses[rid] = (wire.E_REDIRECT, hint)
+            self._release(rid, old)
             return False
-        if shard != old:
-            with self._lock:
-                self._shard_outstanding[old] -= 1
-                self._shard_outstanding[shard] += 1
-            self._rid_shard[rid] = shard
+        self._move(rid, old, shard)
         self._enqueue(shard, msg)
         return True
 
@@ -670,12 +529,11 @@ class ClusterClient:
             # still pick identical deadlines.
             base = max(1, retry_after) << attempt
             jitter = scalar_mix(rid ^ (attempt << 56)) % base
-            self._backoff.append(
-                (self.cluster.clock.now + base + jitter, rid))
+            self._backoff.append((self.clock.now + base + jitter, rid))
 
     def _pump_backoff(self) -> int:
         """Re-issue shed retries whose backoff deadline passed."""
-        now = self.cluster.clock.now
+        now = self.clock.now
         due = [rid for t, rid in self._backoff if t <= now]
         if not due:
             return 0
@@ -688,12 +546,12 @@ class ClusterClient:
 
     def _rebook(self, rid: int) -> bool:
         """Re-book a fully surfaced rid (counters were released when the
-        E_SHED surfaced) and re-issue it along the repaired route."""
+        E_SHED surfaced) and re-issue it along the current route."""
         entry = self._replay.get(rid)
         if entry is None:
             return False
         shard, msg = self._replay_msg(rid, entry)
-        if shard in self.cluster._dead:
+        if shard in self._dead:
             return False
         cls = entry[1] if entry[0] == "op" else entry[3]
         with self._lock:
@@ -703,7 +561,7 @@ class ClusterClient:
         # Re-stamp the issue tick: the latency histogram records this
         # attempt's issue->drain, not time spent parked in backoff.
         issued = self._issued_r if cls == "r" else self._issued_w
-        issued[rid] = self.cluster.clock.now
+        issued[rid] = self.clock.now
         self._arm_timeout(rid)   # re-booked requests regain loss protection
         self._enqueue(shard, msg)
         return True
@@ -711,12 +569,12 @@ class ClusterClient:
     def _pump_timeouts(self) -> int:
         """Resend requests whose tick deadline expired unanswered.
 
-        The resend re-materializes the request against the CURRENT ring
-        (same request id — the server-side dedup cache suppresses the
-        copy if the original survived, or replays the cached ack if only
-        the ack was lost) and re-arms the deadline with doubled backoff.
-        Deadlines for answered/surfaced rids are dropped lazily here."""
-        now = self.cluster.clock.now
+        The resend re-materializes the request against the CURRENT routing
+        (same request id — the server-side dedup cache suppresses the copy
+        if the original survived, or replays the cached ack if only the ack
+        was lost) and re-arms the deadline with doubled backoff.  Deadlines
+        for answered/surfaced rids are dropped lazily here."""
+        now = self.clock.now
         due = [rid for rid, (t, _a) in self._deadlines.items() if t <= now]
         if not due:
             return 0
@@ -732,17 +590,12 @@ class ClusterClient:
                 continue
             attempt = self._deadlines[rid][1]
             shard, msg = self._replay_msg(rid, entry)
-            if shard in self.cluster._dead:
-                # Repaired route still down: leave recovery to the
-                # failover machinery, re-arm one plain window.
+            if shard in self._dead:
+                # Route still down: leave recovery to the failover
+                # machinery, re-arm one plain window.
                 self._deadlines[rid] = (now + tmo, attempt)
                 continue
-            old = self._rid_shard[rid]
-            if shard != old:
-                with self._lock:
-                    self._shard_outstanding[old] -= 1
-                    self._shard_outstanding[shard] += 1
-                self._rid_shard[rid] = shard
+            self._move(rid, self._rid_shard[rid], shard)
             self._enqueue(shard, msg)
             self.stats.timeouts += 1
             self.stats.resends += 1
@@ -753,6 +606,7 @@ class ClusterClient:
 
     def _finalize(self, rid: int) -> None:
         """Drop replay/retry bookkeeping once a result reaches the caller."""
+        self._rid_shard.pop(rid, None)
         self._replay.pop(rid, None)
         self._retries.pop(rid, None)
         self._redirects_seen.pop(rid, None)
@@ -763,20 +617,17 @@ class ClusterClient:
         return self._outstanding
 
     def _drain_busy_devices(self) -> None:
-        """Settle device backlogs — only on shards whose device is busy
-        (the old every-shard ``drain()`` was an idle-cost sweep)."""
-        for srv in self.cluster.servers:
+        """Settle device backlogs — only on servers whose device is busy."""
+        for srv in self._servers:
             if srv.device.busy():
                 srv.device.drain()
 
     def run_until_idle(self, max_iters: int = 200_000) -> None:
-        """Converge on ready-set emptiness + no outstanding requests.
+        """Converge on no runnable server + no outstanding requests.
 
-        ``pump() == 0`` already certifies no shard is runnable or busy (the
-        cluster verifies ``busy()`` on an empty ready set), so the common
-        exit is a single zero-work round — no idle sweeps.  The bounded
-        idle escape survives only for genuinely unanswerable requests
-        (e.g. shed under overload)."""
+        ``pump() == 0`` already certifies nothing is runnable or busy, so
+        the common exit is a single zero-work round.  The bounded idle
+        escape survives only for genuinely unanswerable requests."""
         idle = 0
         for _ in range(max_iters):
             if self.pump():
@@ -791,7 +642,7 @@ class ClusterClient:
             # convergence always burns the full 8-round escape hatch.
             if self._check_terminal(list(self._rid_shard)):
                 continue
-            if self._armed and any(s in self.cluster._dead
+            if self._armed and any(s in self._dead
                                    for s in set(self._rid_shard.values())):
                 # Requests parked on a crashed shard are not unanswerable —
                 # the supervisor will promote a replica within its timeout;
@@ -801,13 +652,12 @@ class ClusterClient:
             idle += 1
             if idle >= 8:
                 return  # idle with requests genuinely unanswerable
-        raise TimeoutError("cluster client did not go idle")
+        raise TimeoutError("client did not go idle")
 
     # -- response access ----------------------------------------------------------------
     def wait(self, rid: int, max_iters: int = 200_000) -> tuple[int, bytes]:
         for _ in range(max_iters):
             if rid in self.responses:
-                self._rid_shard.pop(rid, None)
                 self._finalize(rid)
                 return self.responses.pop(rid)
             if self.pump() == 0:
@@ -820,23 +670,18 @@ class ClusterClient:
         """Collect responses: ``{handle: (status, body)}``.
 
         ``handles=None`` drains whatever has already arrived (one poll;
-        never steps the cluster).  With explicit handles and ``block=True``
+        never steps a server).  With explicit handles and ``block=True``
         this waits for ALL of them, harvesting whichever completes first:
         it pumps once per iteration while collecting every arrived handle —
         a serial per-handle ``wait`` loop would head-of-line block on the
         first one even when later handles (on other shards) had long
-        completed.  Harvesting rides ``poll``'s outstanding-only scan, so
-        only shards that still owe responses are touched.  On idle
-        iterations, handles the servers marked SHED are answered terminally
-        as ``(wire.E_SHED, hint)`` — a shed request can never produce a
-        wire response, so waiting on a timeout heuristic would spin the
-        whole iteration budget."""
+        completed.  Requests a server shed resolve terminally as
+        ``(wire.E_SHED, hint)``, where the hint decodes with
+        :func:`repro.core.wire.decode_shed_hint`."""
         if handles is None:
             self.poll()
             out = dict(self.responses)
-            rid_shard = self._rid_shard
             for rid in out:
-                rid_shard.pop(rid, None)
                 self._finalize(rid)
             self.responses.clear()
             return out
@@ -859,7 +704,7 @@ class ClusterClient:
                 self._drain_busy_devices()
                 self._check_terminal(pending)
             elif self._any_terminal():
-                # Epoch-fence refusals can land while the cluster stays
+                # Epoch-fence refusals can land while the servers stay
                 # busy for a long stretch (a live migration pumps work
                 # through its whole cleanup grace).  Waiting for the
                 # pump to go idle would stall the transparent replay
@@ -872,11 +717,6 @@ class ClusterClient:
                 self._maybe_retry_shed(got, pending)
         raise TimeoutError(f"no response for requests {sorted(pending)[:8]}...")
 
-    def wait_many(self, rids: list[int],
-                  max_iters: int = 200_000) -> dict[int, tuple[int, bytes]]:
-        """Deprecated: ``harvest(rids)``."""
-        return self.harvest(rids, max_iters=max_iters)
-
     def _harvest(self, pending: set[int],
                  got: dict[int, tuple[int, bytes]]) -> set[int]:
         """Move every already-answered rid out of ``self.responses``."""
@@ -885,3 +725,194 @@ class ClusterClient:
             got[rid] = self.responses.pop(rid)
             self._rid_shard.pop(rid, None)
         return done
+
+
+class DDSClient(RequestBook):
+    """A compute-server client of one storage server: the book over ONE
+    connection, sending at once (every issue call flushes it).
+
+    ``tenant`` binds once per connection: every request rides a flow
+    carrying that tenant id, which the server's QoS layer (weighted-fair
+    demux, token-bucket admission, per-tenant stats) keys on.  ``epoch``
+    is stamped on every packet: -1 (the default) is epoch-unaware, and the
+    director accepts untagged packets unconditionally.  An epoch >= 0 or a
+    ``timeout_ticks`` keeps a replay note per request, so an E_REDIRECT or
+    a lost frame is answered by resending the SAME request id.
+    """
+
+    def __init__(self, server: DDSStorageServer, ip: str = "10.0.0.2",
+                 port: int = 31337, tenant: int = 0,
+                 timeout_ticks: int = 0):
+        self.server = server
+        self.tenant = tenant
+        self.timeout_ticks = timeout_ticks
+        self._servers = (server,)
+        super().__init__([ShardConnection(server, ip, port, tenant)],
+                         server.clock)
+
+    @property
+    def epoch(self) -> int:
+        return self.conns[0].epoch
+
+    @epoch.setter
+    def epoch(self, value: int) -> None:
+        self.conns[0].epoch = value
+
+    @property
+    def _replay_on(self) -> bool:
+        return self.epoch >= 0 or self.timeout_ticks > 0
+
+    @property
+    def timeouts(self) -> int:
+        return self.stats.timeouts
+
+    @property
+    def resends(self) -> int:
+        return self.stats.resends
+
+    def submit(self, ops: list[tuple]) -> list[int]:
+        rids = super().submit(ops)
+        self.flush()
+        return rids
+
+    def issue_many(self, shards, build_msg, cls="r", keys=None) -> list[int]:
+        rids = super().issue_many(shards, build_msg, cls, keys)
+        self.flush()
+        return rids
+
+    # -- routing: the one server --------------------------------------------------
+    def _locate(self, fid: int) -> tuple[int, int]:
+        return 0, fid
+
+    def _route_of(self, shard: int) -> int:
+        return 0
+
+    def _shard_for_key(self, key) -> int:
+        return 0
+
+    def _step(self) -> int:
+        return self.server.pump()
+
+    def _on_redirect(self, hint: bytes) -> None:
+        self.epoch = max(self.epoch, wire.decode_redirect_hint(hint))
+
+
+class ClusterClient(RequestBook):
+    """Batched, pipelined client for a :class:`DDSCluster`.
+
+    Requests are routed by cluster-global file id through the cluster's
+    consistent-hash placement (``cluster.locate``); applications with their
+    own keys (e.g. the KV store) route via :meth:`issue_many`.  Messages
+    buffer until :meth:`flush` / :meth:`pump`.
+    """
+
+    _next_base_port = 40000          # distinct flows per client by default
+    _port_lock = threading.Lock()
+
+    def __init__(self, cluster: "DDSCluster", ip: str = "10.0.0.9",
+                 port: int | None = None, tenant: int = 0,
+                 retry_attempts: int = 0, timeout_ticks: int = 0):
+        self.cluster = cluster
+        self.tenant = tenant
+        self._ip = ip
+        if port is None:
+            # Each client needs its own source ports, or two clients' flows
+            # (and therefore their responses) become indistinguishable.
+            with ClusterClient._port_lock:
+                port = ClusterClient._next_base_port
+                ClusterClient._next_base_port += len(cluster.servers)
+        conns = [ShardConnection(srv, ip, port + i, tenant)
+                 for i, srv in enumerate(cluster.servers)]
+        # Failover/reshard awareness, armed on replicated or elastic
+        # clusters: packets are epoch-tagged, issued requests keep a replay
+        # note, and an epoch bump (failover promotion or resharding flip)
+        # transparently re-routes everything parked on the old owner.
+        # Plain clusters pay one attribute test per pump.
+        self._armed = cluster.supervisor is not None or cluster.elastic
+        self._epoch_seen = cluster.epoch
+        epoch = cluster.epoch if self._armed else -1
+        for conn in conns:
+            conn.epoch = epoch
+        self.retry_attempts = retry_attempts
+        self.timeout_ticks = timeout_ticks
+        self._replay_on = (self._armed or retry_attempts > 0
+                           or timeout_ticks > 0)
+        # Live views: the cluster grows and shrinks these in place.
+        self._servers = cluster.servers
+        self._dead = cluster._dead
+        super().__init__(conns, cluster.clock)
+
+    def _grow_conns(self) -> None:
+        """The cluster grew (elastic ``add_shard``): open flows to the new
+        shards.  Ports come from the GLOBAL allocator — extending this
+        client's original contiguous block would collide with whichever
+        client allocated the next block."""
+        cl = self.cluster
+        n = len(cl.servers)
+        if n <= len(self.conns):
+            return
+        add = n - len(self.conns)
+        with ClusterClient._port_lock:
+            base = ClusterClient._next_base_port
+            ClusterClient._next_base_port += add
+        epoch = cl.epoch if self._armed else -1
+        for i in range(add):
+            conn = ShardConnection(cl.servers[len(self.conns)],
+                                   self._ip, base + i, self.tenant)
+            conn.epoch = epoch
+            self.conns.append(conn)
+            self._lat_pos.append(0)
+        with self._lock:
+            while len(self._shard_outstanding) < n:
+                self._shard_outstanding.append(0)
+            while len(self._dirty_flag) < n:
+                self._dirty_flag.append(False)
+
+    # -- routing: the cluster's ring ----------------------------------------------
+    def _locate(self, gfid: int) -> tuple[int, int]:
+        loc = self.cluster.locate(gfid)
+        return loc.shard, loc.local_fid
+
+    def _route_of(self, shard: int) -> int:
+        return self.cluster.route_of(shard)
+
+    def _shard_for_key(self, key) -> int:
+        return self.cluster.shard_for_key(key)
+
+    def _step(self) -> int:
+        """Step the cluster; on replicated or elastic clusters also
+        reconcile failovers (an epoch bump re-routes and replays everything
+        parked on a dead shard)."""
+        work = self.cluster.pump()
+        if self._armed:
+            work += self._sync_epoch()
+        return work
+
+    def _on_redirect(self, hint: bytes) -> None:
+        self._sync_epoch()
+
+    def _sync_epoch(self) -> int:
+        """Adopt the cluster's ring epoch after a failover or flip.
+
+        Updates every connection's outgoing epoch tag and re-routes each
+        unanswered request parked on a now-dead shard — the dead shard can
+        never answer, so without this those rids would hang forever.
+        Returns the number of requests moved (work, for pump loops)."""
+        cur = self.cluster.epoch
+        if cur == self._epoch_seen:
+            return 0
+        self._epoch_seen = cur
+        self._grow_conns()   # an elastic add_shard bumps the epoch too
+        for conn in self.conns:
+            conn.epoch = cur
+        dead = self._dead
+        if not dead:
+            return 0
+        moved = 0
+        responses = self.responses
+        for rid, shard in list(self._rid_shard.items()):
+            if shard not in dead or rid in responses:
+                continue
+            if self._resubmit(rid):
+                moved += 1
+        return moved

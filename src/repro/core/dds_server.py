@@ -15,12 +15,12 @@ Components and their threads (all cooperatively schedulable for tests):
 
 ``DDSStorageServer.pump()`` drives every component one step; ``run_until_idle``
 loops until no component has work, giving deterministic end-to-end tests.
+The clients that speak this protocol live in :mod:`repro.core.client`.
 """
 
 from __future__ import annotations
 
 import struct
-import threading
 from collections import deque
 from dataclasses import dataclass, field
 
@@ -30,13 +30,12 @@ from repro.core import vector, wire
 from repro.core.cache_table import CacheTable
 from repro.core.file_service import FileServiceRunner, SegmentFS
 from repro.core.host_lib import DDSFrontEnd
-from repro.core.lifecycle import (ClientLatency, LifecycleTracker, TickClock,
-                                  TickHistogram)
+from repro.core.lifecycle import LifecycleTracker, TickClock, TickHistogram
 from repro.core.offload import OffloadAPI, OffloadEngine, ReadOp, WriteOp
 from repro.core.qos import QoSProfile, TenantAdmission
 from repro.core.ring import DMAEngine
-from repro.core.traffic import (ApplicationSignature, FiveTuple, Packet,
-                                TrafficDirector, FLAG_SYN)
+from repro.core.traffic import (ApplicationSignature, FiveTuple,
+                                TrafficDirector)
 from repro.storage.blockdev import BlockDevice
 
 # ---------------------------------------------------------------------------
@@ -943,251 +942,3 @@ class _HostApp:
             for flow, batch in per_flow.items():
                 srv.director.host_response_many(flow, batch)
         return n
-
-
-# Unified-surface op spellings -> the wire batch kind ("r"/"w").
-_OP_KIND = {"r": "r", "read": "r", "w": "w", "write": "w"}
-
-
-class DDSClient:
-    """A compute-server client for the benchmark app (batching, outstanding).
-
-    ``tenant`` binds once per connection: every request issued through this
-    client rides a flow carrying that tenant id, which the server's QoS
-    layer (weighted-fair demux, token-bucket admission, per-tenant stats)
-    keys on.  The unified burst surface is :meth:`submit` /
-    :meth:`harvest`; ``write_many``/``send_batch`` remain as thin
-    deprecated wrappers.
-    """
-
-    def __init__(self, server: DDSStorageServer, ip: str = "10.0.0.2",
-                 port: int = 31337, tenant: int = 0,
-                 timeout_ticks: int = 0):
-        self.server = server
-        self.flow = FiveTuple(ip, port, "10.0.0.1", server.config.server_port,
-                              tenant=tenant)
-        self.tenant = tenant
-        self._resp_flow = self.flow.reversed()
-        self._seq = 1  # after SYN
-        self._next_req = 1
-        self._lock = threading.Lock()
-        self.responses: dict[int, tuple[int, bytes]] = {}
-        self._rx_buf = bytearray()
-        # Issue-tick stamps + end-to-end (issue -> drain) per-class latency
-        # (read/write; the dpu/host split for reads is exact in the
-        # server's lifecycle histograms).
-        self._issued_r: dict[int, int] = {}
-        self._issued_w: dict[int, int] = {}
-        self.latency = ClientLatency()
-        # Ring epoch this client believes in, stamped on every packet.  -1
-        # (the default) means epoch-unaware: the director accepts untagged
-        # packets unconditionally.  Epoch-aware clients (>= 0) additionally
-        # keep each outstanding request's encoded message so an E_REDIRECT
-        # can be answered by resubmitting the SAME request id.
-        self.epoch = -1
-        self._replay: dict[int, bytes] = {}
-        # Lossy-wire recovery: after ``timeout_ticks`` of silence ``wait``
-        # resends the request from its replay note with doubled backoff
-        # (the server's dedup cache makes the resend exactly-once).  0 =
-        # timeouts off (lossless-wire behavior, the default).
-        self.timeout_ticks = timeout_ticks
-        self.timeouts = 0
-        self.resends = 0
-        server.director.ingress.push(Packet(self.flow, 0, b"", flags=FLAG_SYN))
-        server.signal()
-        server.director.step()
-
-    def _send(self, payload: bytes) -> None:
-        pkt = Packet(self.flow, self._seq, payload, epoch=self.epoch)
-        if self.server.director.stamp_checksums:
-            pkt.csum = vector.checksum64(payload)
-        self.server.director.ingress.push(pkt)
-        self._seq += len(payload)
-        self.server.signal()   # client sends are a scheduler wakeup source
-
-    def read(self, file_id: int, offset: int, nbytes: int) -> int:
-        with self._lock:
-            rid = self._next_req
-            self._next_req += 1
-        self._issued_r[rid] = self.server.clock.now
-        msg = encode_app_read(rid, file_id, offset, nbytes)
-        if self.epoch >= 0 or self.timeout_ticks:
-            self._replay[rid] = msg
-        self._send(encode_batch([msg]))
-        return rid
-
-    def write(self, file_id: int, offset: int, data: bytes) -> int:
-        with self._lock:
-            rid = self._next_req
-            self._next_req += 1
-        self._issued_w[rid] = self.server.clock.now
-        msg = encode_app_write(rid, file_id, offset, data)
-        if self.epoch >= 0 or self.timeout_ticks:
-            self._replay[rid] = msg
-        self._send(encode_batch([msg]))
-        return rid
-
-    # -- unified burst surface --------------------------------------------------------
-    def submit(self, ops: list[tuple]) -> list[int]:
-        """Issue a burst of operations in ONE network message; returns one
-        handle (request id) per op, in order.
-
-        Ops are ``("r"|"read", file_id, offset, nbytes)`` or
-        ``("w"|"write", file_id, offset, data)``.  The connection's tenant
-        rides the flow, so tenant context is carried once per batch — never
-        per call.  Harvest results with :meth:`harvest`.
-        """
-        return self.send_batch([(_OP_KIND[op[0]],) + tuple(op[1:])
-                                for op in ops])
-
-    def harvest(self, handles=None, block: bool = True,
-                max_iters: int = 200_000) -> dict[int, tuple[int, bytes]]:
-        """Collect responses: ``{handle: (status, body)}``.
-
-        ``handles=None`` harvests whatever has already arrived (one drain;
-        never pumps).  With explicit handles and ``block=True`` this pumps
-        until EVERY handle resolves — requests the server shed terminally
-        resolve as ``(wire.E_SHED, hint)`` where the hint decodes with
-        :func:`repro.core.wire.decode_shed_hint`.
-        """
-        self.collect()
-        responses = self.responses
-        if handles is None:
-            out = dict(responses)
-            responses.clear()
-            return out
-        out = {}
-        lt = self.server.lifecycle
-        pending = [rid for rid in handles if rid not in responses]
-        for rid in handles:
-            if rid in responses:
-                out[rid] = responses.pop(rid)
-        if not block:
-            for rid in list(pending):
-                term = lt.take_terminal(self.flow, rid)
-                if term is not None:
-                    self._issued_r.pop(rid, None)
-                    self._issued_w.pop(rid, None)
-                    self._replay.pop(rid, None)
-                    out[rid] = term
-                    pending.remove(rid)
-            return out
-        for rid in pending:
-            out[rid] = self.wait(rid, max_iters)
-        return {rid: out[rid] for rid in handles if rid in out}
-
-    def send_batch(self, msgs: list[tuple]) -> list[int]:
-        """msgs: list of ("r", fid, off, n) / ("w", fid, off, data).
-
-        Deprecated spelling of :meth:`submit` (kept as a thin wrapper
-        target; prefer ``submit``, which also accepts the long op names).
-        """
-        encoded, rids = [], []
-        now = self.server.clock.now
-        with self._lock:
-            for m in msgs:
-                rid = self._next_req
-                self._next_req += 1
-                rids.append(rid)
-                if m[0] == "r":
-                    encoded.append(encode_app_read(rid, m[1], m[2], m[3]))
-                    self._issued_r[rid] = now
-                else:
-                    encoded.append(encode_app_write(rid, m[1], m[2], m[3]))
-                    self._issued_w[rid] = now
-        if self.epoch >= 0 or self.timeout_ticks:
-            for rid, msg in zip(rids, encoded):
-                self._replay[rid] = msg
-        self._send(encode_batch(encoded))
-        return rids
-
-    def write_many(self, writes: list[tuple]) -> list[int]:
-        """Issue a burst of ``(file_id, offset, data)`` writes in ONE
-        network message — the write-side mirror of the cluster client's
-        ``read_many``: one rid-range reservation, one batched send."""
-        n = len(writes)
-        with self._lock:
-            first = self._next_req
-            self._next_req += n
-        rids = list(range(first, first + n))
-        now = self.server.clock.now
-        for rid in rids:
-            self._issued_w[rid] = now
-        self._send(encode_batch([encode_app_write(rid, fid, off, data)
-                                 for rid, (fid, off, data)
-                                 in zip(rids, writes)]))
-        return rids
-
-    # -- response collection ---------------------------------------------------------
-    def collect(self) -> int:
-        """Drain OUR flow's responses off the demuxed client wire (shared
-        implementation with the cluster's shard connections)."""
-        order: list[int] = []
-        n = drain_client_flow(self.server.director, self._resp_flow,
-                              self._rx_buf, self.responses, order)
-        if order:
-            self._record_latency(order)
-        return n
-
-    def _record_latency(self, rids: list[int]) -> None:
-        """End-to-end issue->drain ticks, classified read/write at issue."""
-        latency = self.latency
-        now = self.server.clock.now
-        reads = self._issued_r
-        writes = self._issued_w
-        for rid in rids:
-            t0 = reads.pop(rid, None)
-            if t0 is not None:
-                latency.record("read", now - t0)
-                continue
-            t0 = writes.pop(rid, None)
-            if t0 is not None:
-                latency.record("write", now - t0)
-
-    def wait(self, rid: int, max_iters: int = 200_000) -> tuple[int, bytes]:
-        # ``pump()`` already polls the device whenever the offload engine or
-        # the host path is busy; the old unconditional per-spin
-        # ``device.poll()`` here was pure overhead on idle iterations.
-        lt = self.server.lifecycle
-        tmo = self.timeout_ticks
-        clock = self.server.clock
-        deadline = clock.now + tmo if tmo else None
-        attempt = 0
-        for _ in range(max_iters):
-            self.collect()
-            if rid in self.responses:
-                self._replay.pop(rid, None)
-                return self.responses.pop(rid)
-            if deadline is not None and clock.now >= deadline:
-                # Tick-based timeout: the request or its response was lost
-                # on the wire.  Resend from the replay note with doubled
-                # backoff — the server's dedup cache suppresses the copy
-                # (or replays the cached ack) if the original survived.
-                msg = self._replay.get(rid)
-                if msg is not None:
-                    self.timeouts += 1
-                    self.resends += 1
-                    self._send(encode_batch([msg]))
-                attempt += 1
-                deadline = clock.now + (tmo << min(attempt, 6))
-            term = lt.take_terminal(self.flow, rid)
-            if term is not None:
-                code, hint = term
-                if code == wire.E_REDIRECT and rid in self._replay:
-                    # Retryable: adopt the repaired ring's epoch and
-                    # resubmit the SAME request id (the old owner never
-                    # answered it, so the id cannot alias).
-                    self.epoch = max(self.epoch,
-                                     wire.decode_redirect_hint(hint))
-                    self._send(encode_batch([self._replay[rid]]))
-                    continue
-                # Terminal: the request was shed under overload or by
-                # admission — no response will EVER arrive.  Surface it
-                # (with the retry-after hint as the body) instead of
-                # spinning the full iteration budget into a timeout.
-                self._issued_r.pop(rid, None)
-                self._issued_w.pop(rid, None)
-                self._replay.pop(rid, None)
-                return (code, hint)
-            self.server.pump()
-        raise TimeoutError(f"no response for request {rid}")

@@ -190,16 +190,14 @@ class PagedKVEngine:
             self.hits += 1
             self.lru.append(key)  # refresh
             return None  # already in HBM; caller uses the block table
-        from repro.core.dds_server import DDSClient, encode_batch
+        from repro.core.client import DDSClient
         from repro.storage.pagestore import PageStore
         if self._client is None:
             self._client = DDSClient(self.store.server)
-        rid = self._client._next_req
-        self._client._next_req += 1
-        msg = PageStore.encode_get(rid, self._page_id(key),
-                                   self.versions.get(key, 0))
-        self._client._send(encode_batch([msg]))
-        status, body = self._client.wait(rid)
+        page, version = self._page_id(key), self.versions.get(key, 0)
+        rid = self._client.send_raw(
+            0, lambda rid: PageStore.encode_get(rid, page, version))
+        status, body = self._client.harvest([rid])[rid]
         self.fetches += 1
         if status != 0:
             return None

@@ -27,8 +27,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import vector, wire
-from repro.core.client import ClusterClient
-from repro.core.dds_server import DDSClient, DDSStorageServer, ServerConfig
+from repro.core.client import ClusterClient, DDSClient
+from repro.core.dds_server import DDSStorageServer, ServerConfig
 from repro.core.faultnet import FaultSchedule, FaultWire, wrap_director
 from repro.core.traffic import FiveTuple, FlowDemuxWire, Packet, Wire
 from repro.distributed.cluster import DDSCluster

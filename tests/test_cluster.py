@@ -59,7 +59,7 @@ def test_client_batches_per_shard_messages(loaded_cluster):
     shards_used = {cl.locate(f).shard for f in fids}
     assert cc.stats.batches_sent == len(shards_used)
     assert cc.stats.messages_sent == len(rids)
-    res = cc.wait_many(rids)
+    res = cc.harvest(rids)
     assert all(s == wire.E_OK for s, _ in res.values())
 
 
@@ -71,7 +71,7 @@ def test_pipelined_responses_preserve_per_shard_issue_order(loaded_cluster):
     for round_ in range(5):
         rids += [cc.read(f, 256 * round_, 128) for f in fids]
         cc.flush()                      # new batch; do NOT wait
-    res = cc.wait_many(rids)
+    res = cc.harvest(rids)
     assert all(s == wire.E_OK for s, _ in res.values())
     for conn in cc.conns:
         issued = [r for r in rids if r in set(conn.arrival_order)]
@@ -229,9 +229,9 @@ def test_kv_get_burst_uses_one_batched_cache_lookup(kv):
     c.run_until_idle()
     shard_batches = {i: s["cache"]["batched_lookups"]
                      for i, s in enumerate(store.shard_stats())}
-    rids = c.get_many(keys)
+    rids = c.submit([("get", k) for k in keys])
     c.flush()
-    res = c.net.wait_many(rids)
+    res = c.harvest(rids)
     assert all(s == wire.E_OK for s, _ in res.values())
     after = store.shard_stats()
     for i, s in enumerate(after):
@@ -246,20 +246,20 @@ def test_kv_get_burst_uses_one_batched_cache_lookup(kv):
 def test_kv_burst_apis_roundtrip(kv):
     store, c = kv
     items = [(b"bk-%d" % i, b"bv-%d" % i) for i in range(10)]
-    put_rids = c.put_many(items)
+    put_rids = c.submit([("put", k, v) for k, v in items])
     c.flush()
     for rid in put_rids:
         c.wait_put(rid)
-    get_rids = c.get_many([k for k, _ in items])
+    get_rids = c.submit([("get", k) for k, _ in items])
     c.flush()
-    res = c.net.wait_many(get_rids)
+    res = c.harvest(get_rids)
     from repro.apps.kv_store import decode_record
     for (k, v), rid in zip(items, get_rids):
         st_, body = res[rid]
         assert st_ == wire.E_OK and decode_record(body) == (k, v)
-    del_rids = c.delete_many([k for k, _ in items[:5]])
+    del_rids = c.submit([("delete", k) for k, _ in items[:5]])
     c.flush()
-    res = c.net.wait_many(del_rids)
+    res = c.harvest(del_rids)
     assert all(s == wire.E_OK for s, _ in res.values())
     assert c.wait_value(c.get(items[0][0])) is None
     assert c.wait_value(c.get(items[9][0])) == b"bv-9"

@@ -13,20 +13,22 @@ Covers the replicated-shard overhaul end to end:
   * the supervisor: tick-clock heartbeats, deterministic detection,
     replica promotion, ring repair and epoch bump;
   * client transparency: the epoch fence refuses stale-epoch packets with
-    retryable redirects; all three clients (DDSClient, ClusterClient,
-    KVClient) replay against the repaired ring with the same request ids;
+    retryable redirects; ClusterClient and KVClient replay against the
+    repaired ring with the same request ids (the redirect replay itself is
+    pinned on both clients in ``test_request_book.py``);
   * a property-style crash sweep: kill each shard at a range of ticks
     across a deterministic run — zero lost acknowledged writes;
   * KV promotion: the adopted log copy rebuilds the index, stale DPU
     cache-table entries are replaced, adopted invalidation views work;
-  * shed retry with bounded exponential backoff honoring ``retry_after``.
+  * shed retry with bounded exponential backoff honoring ``retry_after``
+    (retry disabled is the shed case of ``test_request_book.py``).
 """
 
 import pytest
 
 from repro.core import wire
 from repro.core.client import ClusterClient
-from repro.core.dds_server import DDSClient, DDSStorageServer, ServerConfig
+from repro.core.dds_server import ServerConfig
 from repro.core.file_service import FileServiceRunner, SegmentFS
 from repro.core.host_lib import DDSFrontEnd
 from repro.core.qos import QoSProfile
@@ -250,22 +252,6 @@ def test_crash_at_schedules_deterministic_kill():
 # ---------------------------------------------------------------------------
 
 
-def test_epoch_fence_redirect_roundtrip_ddsclient():
-    srv = DDSStorageServer(ServerConfig())
-    fid = srv.frontend.create_file("e")
-    srv.frontend.write_sync(fid, 0, b"E" * 64)
-    srv.run_until_idle()
-    srv.director.epoch_of = lambda: 3
-    srv.director.on_stale_epoch = srv._on_stale_epoch
-    c = DDSClient(srv)
-    c.epoch = 1                      # stale: fence must refuse + redirect
-    rid = c.read(fid, 0, 64)
-    status, body = c.wait(rid)
-    assert (status, body) == (wire.E_OK, b"E" * 64)   # transparent replay
-    assert c.epoch == 3              # adopted the advertised epoch
-    assert srv.lifecycle.redirects >= 1
-
-
 def test_cluster_client_replays_through_failover():
     cl = make_cluster(3)
     files = [cl.create_file(f"f{i}") for i in range(12)]
@@ -420,17 +406,6 @@ def test_shed_retry_cap_surfaces_terminal_error():
             tenant, ra = wire.decode_shed_hint(body)
             assert tenant == 7 and ra >= 1
     assert c.outstanding() == 0               # nothing leaked
-
-
-def test_retry_disabled_surfaces_shed_immediately():
-    cl = DDSCluster(1, ServerConfig(
-        device_capacity=1 << 24,
-        qos=QoSProfile(tenant_rates={7: 1.0}, tenant_bursts={7: 2.0})))
-    g = cl.create_file("s")
-    cl.write_sync(g, 0, b"\x01" * 4096)
-    c = ClusterClient(cl, tenant=7)           # retry_attempts=0
-    res = c.harvest(c.submit([("r", g, 0, 64)] * 6))
-    assert sum(1 for s, _ in res.values() if s == wire.E_SHED) == 4
 
 
 # ---------------------------------------------------------------------------

@@ -22,7 +22,8 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.core import wire
-from repro.core.dds_server import (APP_RESP_HDR, DDSClient, DDSStorageServer,
+from repro.core.client import DDSClient
+from repro.core.dds_server import (APP_RESP_HDR, DDSStorageServer,
                                    ServerConfig, decode_batch, encode_batch,
                                    reassemble_responses)
 from repro.core.offload import PKT_HEADROOM, SlabPool
@@ -255,9 +256,9 @@ def test_unframe_batch_views_match_bytes():
     assert all(isinstance(m, memoryview) for m in out)
 
 
-# -- wait_many: no head-of-line blocking ------------------------------------------------
+# -- harvest: no head-of-line blocking --------------------------------------------------
 
-def test_wait_many_harvests_out_of_order_completions():
+def test_harvest_collects_out_of_order_completions():
     """rids are collected as they arrive, regardless of the order given."""
     from repro.core.client import ClusterClient
     from repro.distributed.cluster import DDSCluster
@@ -271,7 +272,7 @@ def test_wait_many_harvests_out_of_order_completions():
     cc.flush()
     # ask for the rids in REVERSE order: a serial per-rid wait would block
     # on the last-issued rid while all the others sit ready
-    res = cc.wait_many(list(reversed(rids)))
+    res = cc.harvest(list(reversed(rids)))
     assert set(res) == set(rids)
     for k, rid in enumerate(rids):
         status, body = res[rid]
@@ -290,7 +291,7 @@ def test_undrained_responses_survive_later_reads():
     cli = DDSClient(srv)
     # issue many reads but do NOT collect between pumps: every response
     # sits on the demuxed wire referencing pool memory
-    rids = cli.send_batch([("r", fid, i * 64, 64) for i in range(64)])
+    rids = cli.submit([("r", fid, i * 64, 64) for i in range(64)])
     for _ in range(200):
         if len(srv.director.to_client) >= 64:
             break
@@ -298,7 +299,7 @@ def test_undrained_responses_survive_later_reads():
         srv.device.drain()
     assert srv.offload.pool.in_use() > 0         # blocks still owned by wire
     for _ in range(2000):
-        cli.collect()
+        cli.poll()
         if len(cli.responses) == len(rids):
             break
         srv.pump()

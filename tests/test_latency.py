@@ -18,7 +18,7 @@ Covers the PR-5 latency machinery end to end:
     of files whose writes are still in the file-service pipeline to the
     host FIFO (read-your-writes for anything the file service accepted);
   * terminal SHED status: a request the file service shed under overload is
-    surfaced by ``DDSClient.wait`` / ``ClusterClient.wait_many`` as
+    surfaced by ``DDSClient.wait`` / ``ClusterClient.harvest`` as
     ``wire.E_SHED`` instead of spinning into a timeout.
 """
 
@@ -29,8 +29,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import wire
-from repro.core.client import ClusterClient
-from repro.core.dds_server import DDSClient, DDSStorageServer, ServerConfig
+from repro.core.client import ClusterClient, DDSClient
+from repro.core.dds_server import DDSStorageServer, ServerConfig
 from repro.core.file_service import FileServiceRunner, SegmentFS
 from repro.core.host_lib import DDSFrontEnd
 from repro.core.lifecycle import TickClock, TickHistogram
@@ -164,10 +164,10 @@ def _mixed_run(seed: int) -> str:
     cli = ClusterClient(cluster, port=45500)
     rng = random.Random(seed)
     for _ in range(30):
-        cli.read_many([(fids[rng.randrange(6)], rng.randrange(0, 7936), 128)
-                       for _ in range(8)])
-        cli.write_many([(fids[rng.randrange(6)], rng.randrange(0, 15) * 512,
-                         b"z" * 128) for _ in range(4)])
+        cli.submit([("r", fids[rng.randrange(6)], rng.randrange(0, 7936), 128)
+                    for _ in range(8)])
+        cli.submit([("w", fids[rng.randrange(6)], rng.randrange(0, 15) * 512,
+                     b"z" * 128) for _ in range(4)])
         cli.flush()
         cluster.pump()
         cli.poll()
@@ -204,11 +204,11 @@ def test_cluster_latency_stats_classes():
     cluster.write_sync(fid, 0, b"\x05" * 4096)
     cli = ClusterClient(cluster, port=45600)
     rng = random.Random(1)
-    rids = cli.read_many([(fid, rng.randrange(0, 3968), 64)
-                          for _ in range(16)])
-    rids += cli.write_many([(fid, 4096, b"y" * 64)])
+    rids = cli.submit([("r", fid, rng.randrange(0, 3968), 64)
+                       for _ in range(16)])
+    rids += cli.submit([("w", fid, 4096, b"y" * 64)])
     cli.flush()
-    cli.wait_many(rids)
+    cli.harvest(rids)
     stats = cluster.latency_stats()
     assert stats["classes"]["dpu_read"]["count"] == 16
     assert stats["classes"]["write"]["count"] == 1
@@ -235,10 +235,10 @@ def test_per_flow_fifo_preserved_under_priority_demux():
     writer = ClusterClient(cluster, port=45800)
     read_rids, write_rids = [], []
     for r in range(6):
-        read_rids += reader.read_many([(fid, 128 * i, 64)
-                                       for i in range(10)])
-        write_rids += writer.write_many([(fid, 65536 + 1024 * i, b"q" * 64)
-                                         for i in range(5)])
+        read_rids += reader.submit([("r", fid, 128 * i, 64)
+                                    for i in range(10)])
+        write_rids += writer.submit([("w", fid, 65536 + 1024 * i, b"q" * 64)
+                                     for i in range(5)])
         reader.flush()
         writer.flush()
         cluster.pump()
@@ -355,8 +355,8 @@ def test_read_write_fence_bounces_fenced_reads_to_host():
     srv.frontend.write_sync(fid, 0, b"\x00" * 65536)
     srv.run_until_idle()
     # Strided (non-coalescing) writes: a real multi-op device backlog.
-    wrids = cli.write_many([(fid, 1024 * i, bytes([i]) * 128)
-                            for i in range(24)])
+    wrids = cli.submit([("w", fid, 1024 * i, bytes([i]) * 128)
+                        for i in range(24)])
     srv.pump()                           # writes reach the file service
     assert srv.file_service.write_inflight.get(fid, 0) > 0
     rrid = cli.read(fid, 1024 * 23, 128)   # read bytes of the LAST write
@@ -464,7 +464,7 @@ def test_shed_during_submit_many_reentry_is_not_lost():
     srv.run_until_idle()                # server quiesces; nothing pinned
 
 
-def test_cluster_wait_many_surfaces_shed():
+def test_cluster_harvest_surfaces_shed():
     cluster = DDSCluster(num_shards=1,
                          config=ServerConfig(device_capacity=1 << 24))
     fid = cluster.create_file("shedmany")
@@ -480,7 +480,7 @@ def test_cluster_wait_many_surfaces_shed():
     frontend_rids = list(srv.host_app._inflight)
     assert frontend_rids
     srv.file_service.shed_hook(frontend_rids[0])
-    got = cli.wait_many([ok_rid, shed_rid], max_iters=20_000)
+    got = cli.harvest([ok_rid, shed_rid], max_iters=20_000)
     assert got[ok_rid][0] == wire.E_OK
     assert got[shed_rid][0] == wire.E_SHED
     assert wire.decode_shed_hint(got[shed_rid][1]) == (0, 1)

@@ -16,8 +16,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import wire
-from repro.core.dds_server import DDSClient, DDSStorageServer, ServerConfig, \
-    encode_batch
+from repro.core.client import DDSClient
+from repro.core.dds_server import DDSStorageServer, ServerConfig
 from repro.storage.pagestore import PageStore
 
 
@@ -58,10 +58,10 @@ def test_offloaded_responses_in_request_order(reqs):
     srv.frontend.write_sync(fid, 0, bytes(range(256)) * 64)
     srv.run_until_idle()
     cli = DDSClient(srv)
-    rids = cli.send_batch([("r", fid, off * 64, n) for off, n in reqs])
+    rids = cli.submit([("r", fid, off * 64, n) for off, n in reqs])
     seen = []
     for _ in range(400_000):
-        cli.collect()
+        cli.poll()
         for r in rids:
             if r in cli.responses and r not in seen:
                 seen.append(r)
@@ -82,7 +82,6 @@ def test_page_store_never_serves_stale(data):
     ps = PageStore(page_size=512, num_pages=64)
     lsns = {}
     cli = DDSClient(ps.server)
-    rid = 0
     for step in range(data.draw(st.integers(4, 12))):
         page = data.draw(st.integers(0, 7))
         action = data.draw(st.sampled_from(["replay", "host_read", "get"]))
@@ -93,10 +92,9 @@ def test_page_store_never_serves_stale(data):
         elif action == "host_read" and page in lsns:
             ps.host_read_for_update(page)           # invalidates DPU cache
         elif page in lsns:
-            rid += 1
-            cli._send(encode_batch([PageStore.encode_get(
-                rid, page, lsns[page])]))
-            status, body = cli.wait(rid)
+            want = lsns[page]
+            status, body = cli.wait(cli.send_raw(
+                0, lambda rid: PageStore.encode_get(rid, page, want)))
             assert status == wire.E_OK
             lsn, payload = PageStore.decode_page(body)
             assert lsn == lsns[page]                # never stale

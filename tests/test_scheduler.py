@@ -85,7 +85,7 @@ def test_busy_server_stays_runnable_until_drained():
                     f"shard {i} is busy but not runnable (lost wakeup)"
         if cl.pump() + cli.poll() == 0 and cli.outstanding() == 0:
             break
-    res = cli.wait_many(rids)
+    res = cli.harvest(rids)
     assert all(s == wire.E_OK for s, _ in res.values())
 
 
@@ -135,7 +135,7 @@ def test_idle_shards_take_zero_pump_steps():
     for r in range(4):
         rids += [cli.read(f, 32 * r, 64) for f in mine]
         cli.flush()
-    res = cli.wait_many(rids)
+    res = cli.harvest(rids)
     assert all(s == wire.E_OK for s, _ in res.values())
     deltas = [after - b for after, b in zip(cl.pump_steps, before)]
     assert deltas[target] > 0

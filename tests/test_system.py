@@ -3,8 +3,8 @@
 import pytest
 
 from repro.core import wire
-from repro.core.dds_server import (DDSClient, DDSStorageServer, ServerConfig,
-                                   encode_batch)
+from repro.core.client import DDSClient
+from repro.core.dds_server import DDSStorageServer, ServerConfig
 from repro.storage.pagestore import KVStoreServer, PageStore
 
 
@@ -46,7 +46,7 @@ def test_mixed_batch_splits(server):
     """One network message with reads+writes splits between DPU and host."""
     srv, fid = server
     cli = DDSClient(srv)
-    rids = cli.send_batch([("r", fid, 0, 64), ("w", fid, 4096, b"x" * 64),
+    rids = cli.submit([("r", fid, 0, 64), ("w", fid, 4096, b"x" * 64),
                            ("r", fid, 64, 64)])
     results = {r: cli.wait(r) for r in rids}
     assert all(status == wire.E_OK for status, _ in results.values())
@@ -90,19 +90,18 @@ def test_page_store_lsn_semantics():
     ps = PageStore()
     ps.replay(3, lsn=50, payload=b"v50")
     cli = DDSClient(ps.server)
-    cli._send(encode_batch([PageStore.encode_get(1, 3, 50)]))
-    status, body = cli.wait(1)
+    status, body = cli.wait(cli.send_raw(
+        0, lambda rid: PageStore.encode_get(rid, 3, 50)))
     lsn, payload = PageStore.decode_page(body)
     assert (status, lsn) == (wire.E_OK, 50) and payload[:3] == b"v50"
     assert ps.server.offload.stats.completed == 1   # served by the DPU
     # requested LSN newer than cached -> host serves (partial offload)
-    cli._send(encode_batch([PageStore.encode_get(2, 3, 99)]))
-    status, body = cli.wait(2)
+    status, body = cli.wait(cli.send_raw(
+        0, lambda rid: PageStore.encode_get(rid, 3, 99)))
     assert status == wire.E_OK and ps.host_served == 1
     # invalidate-on-read: host pulls the page back -> next GET -> host
     ps.host_read_for_update(3)
-    cli._send(encode_batch([PageStore.encode_get(3, 3, 10)]))
-    cli.wait(3)
+    cli.wait(cli.send_raw(0, lambda rid: PageStore.encode_get(rid, 3, 10)))
     assert ps.host_served == 2
 
 
@@ -112,17 +111,17 @@ def test_kv_store_tail_vs_disk():
     kv.flush()                                # -> cache-on-write fires
     kv.upsert(b"hot", b"tail-value")          # stays in the mutable tail
     cli = DDSClient(kv.server)
-    cli._send(encode_batch([KVStoreServer.encode_get(1, b"cold")]))
-    status, body = cli.wait(1)
+    status, body = cli.wait(cli.send_raw(
+        0, lambda rid: KVStoreServer.encode_get(rid, b"cold")))
     k, v = KVStoreServer.decode_record(body)
     assert (k, v) == (b"cold", b"on-disk-value")
     assert kv.server.offload.stats.completed == 1   # DPU-served
-    cli._send(encode_batch([KVStoreServer.encode_get(2, b"hot")]))
-    status, body = cli.wait(2)
+    status, body = cli.wait(cli.send_raw(
+        0, lambda rid: KVStoreServer.encode_get(rid, b"hot")))
     k, v = KVStoreServer.decode_record(body)
     assert (k, v) == (b"hot", b"tail-value")        # host-served (RMW data)
-    cli._send(encode_batch([KVStoreServer.encode_get(3, b"missing")]))
-    status, body = cli.wait(3)
+    status, body = cli.wait(cli.send_raw(
+        0, lambda rid: KVStoreServer.encode_get(rid, b"missing")))
     assert status == wire.E_NOENT
 
 

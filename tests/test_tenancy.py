@@ -18,21 +18,20 @@ Covers the PR-6 tenancy machinery end to end:
     counters and latency stats never move;
   * tick determinism: two identical two-tenant interference runs produce
     byte-identical per-tenant latency histograms;
-  * the unified ``submit``/``harvest`` surface returns exactly what the
-    deprecated ``read_many``/``write_many``/``get_many``/``put_many``/
-    ``delete_many``/``wait_many`` wrappers return.
+  * the ``submit``/``harvest`` surface: a mixed burst answers exactly as
+    the same ops issued one at a time through the one-op forms.
 """
 
 import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import wire
-from repro.core.client import ClusterClient
-from repro.core.dds_server import DDSClient, DDSStorageServer, ServerConfig
+from repro.core.client import ClusterClient, DDSClient
+from repro.core.dds_server import DDSStorageServer, ServerConfig
 from repro.core.lifecycle import TickClock
 from repro.core.qos import QoSProfile, TenantAdmission, TokenBucket
 from repro.core.traffic import FiveTuple, FlowDemuxWire, Packet, TenantFairQueue
-from repro.apps.kv_store import KVClient, ShardedKVStore
+from repro.apps.kv_store import KVClient, ShardedKVStore, decode_record
 from repro.distributed.cluster import DDSCluster
 
 
@@ -326,35 +325,46 @@ def test_two_tenant_interference_is_tick_deterministic():
 
 
 # ---------------------------------------------------------------------------
-# Unified submit/harvest surface == deprecated wrappers
+# submit/harvest: a mixed burst answers as the one-op forms do
 # ---------------------------------------------------------------------------
 
 
-def test_dds_client_submit_harvest_matches_wrappers():
+def _dds_ops_run(burst: bool):
+    """One mixed read/write sequence on a fresh server, issued as ONE
+    ``submit`` burst or one op at a time through ``read``/``write``."""
     srv = DDSStorageServer(ServerConfig(device_capacity=1 << 24))
     cli = DDSClient(srv)
     fid = srv.frontend.create_file("uni")
     srv.frontend.write_sync(fid, 0, bytes(range(256)) * 16)
     srv.run_until_idle()
-    rids = cli.submit([("w", fid, 0, b"A" * 64),
-                       ("read", fid, 64, 32),
-                       ("write", fid, 128, b"B" * 16),
-                       ("r", fid, 512, 64)])
+    ops = [("w", fid, 0, b"A" * 64), ("read", fid, 64, 32),
+           ("write", fid, 128, b"B" * 16), ("r", fid, 512, 64)]
+    if burst:
+        rids = cli.submit(ops)
+    else:
+        rids = [(cli.read if op[0] in ("r", "read") else cli.write)(*op[1:])
+                for op in ops]
     got = cli.harvest(rids)
-    assert [got[r][0] for r in rids] == [wire.E_OK] * 4
-    assert got[rids[1]][1] == (bytes(range(256)) * 16)[64:96]
-    assert got[rids[3]][1] == (bytes(range(256)) * 16)[512:576]
-    # The batch's writes landed (visible once the pipeline quiesced).
-    srv.run_until_idle()
-    chk = cli.submit([("r", fid, 0, 64)])
-    assert cli.harvest(chk)[chk[0]][1] == b"A" * 64
-    # Deprecated wrappers ride the same path.
-    wr = cli.write_many([(fid, 256, b"C" * 8)])
-    assert cli.wait(wr[0])[0] == wire.E_OK
+    srv.run_until_idle()       # the writes land once the pipeline quiesced
+    chk = cli.submit([("r", fid, 0, 64), ("r", fid, 128, 16)])
+    after = cli.harvest(chk)
     # harvest(None) drains whatever already arrived.
-    r2 = cli.submit([("r", fid, 128, 16)])
-    cli.harvest(r2)
+    cli.harvest(cli.submit([("r", fid, 128, 16)]))
     assert cli.harvest() == {}
+    return ([got[r] for r in rids], [after[r] for r in chk],
+            cli.stats.batches_sent)
+
+
+def test_dds_client_submit_harvest_matches_wrappers():
+    burst, burst_after, burst_batches = _dds_ops_run(True)
+    single, single_after, single_batches = _dds_ops_run(False)
+    assert burst == single and burst_after == single_after
+    assert [s for s, _ in burst] == [wire.E_OK] * 4
+    assert burst[1][1] == (bytes(range(256)) * 16)[64:96]
+    assert burst[3][1] == (bytes(range(256)) * 16)[512:576]
+    assert burst_after == [(wire.E_OK, b"A" * 64), (wire.E_OK, b"B" * 16)]
+    # One network message for the burst, one per op otherwise.
+    assert (burst_batches, single_batches) == (3, 6)
 
 
 def test_cluster_client_submit_mixed_batch_and_harvest_nonblocking():
@@ -372,9 +382,9 @@ def test_cluster_client_submit_mixed_batch_and_harvest_nonblocking():
     assert [got[r][0] for r in rids] == [wire.E_OK] * 4
     assert got[rids[1]][1] == b"\x07" * 64
     assert got[rids[3]][1] == b"\x07" * 64
-    # read_many/write_many/wait_many wrappers still answer identically.
-    r = cli.read_many([(fids[1], 0, 16), (fids[2], 64, 64)])
-    got2 = cli.wait_many(r)
+    # The one-op forms answer from the same book.
+    r = [cli.read(fids[1], 0, 16), cli.read(fids[2], 64, 64)]
+    got2 = cli.harvest(r)
     assert got2[r[1]][1] == b"y" * 64
     assert cli.outstanding() == 0
     # Non-blocking harvest returns only what has arrived — never raises.
@@ -384,30 +394,47 @@ def test_cluster_client_submit_mixed_batch_and_harvest_nonblocking():
     cli.harvest(r3)
 
 
-def test_kv_client_submit_mixed_and_wrappers():
+_KV_PHASES = [
+    [("put", b"k1", b"v1" * 8), ("put", b"k2", b"v2" * 8),
+     ("put", b"k3", b"v3")],
+    [("get", b"k1"), ("delete", b"k2"), ("get", b"k3"),
+     ("put", b"k4", b"v4")],
+    [("get", b"k2"), ("get", b"k4"), ("delete", b"k4")],
+    [("get", b"k4"), ("get", b"k1")],
+]
+
+
+def _kv_ops_run(burst: bool):
+    """The phases above on a fresh two-shard store, each phase issued as
+    ONE mixed ``submit`` burst or one op at a time through
+    ``put``/``get``/``delete``; every phase is harvested before the next."""
     store = ShardedKVStore(num_shards=2,
                            config=ServerConfig(device_capacity=1 << 24))
     cli = KVClient(store, tenant=5)
-    rids = cli.submit([("put", b"k1", b"v1" * 8),
-                       ("put", b"k2", b"v2" * 8)])
-    got = cli.harvest(rids)
-    assert all(s == wire.E_OK for s, _ in got.values())
-    rids = cli.submit([("get", b"k1"), ("delete", b"k2")])
-    got = cli.harvest(rids)
-    assert got[rids[0]][0] == wire.E_OK
-    assert got[rids[1]][0] == wire.E_OK
-    # After the DEL's ack, the mapping is gone (invalidate-on-read fired).
-    assert cli.wait_value(cli.get(b"k2")) is None
-    # Deprecated wrappers.
-    cli.wait_put(cli.put(b"k3", b"v3"))
-    assert cli.wait_value(cli.get(b"k3")) == b"v3"
-    g = cli.get_many([b"k1", b"k3"])
-    got = cli.net.wait_many(g)
-    assert all(s == wire.E_OK for s, _ in got.values())
-    p = cli.put_many([(b"k4", b"v4"), (b"k5", b"v5")])
-    d = cli.delete_many([b"k4"])
-    got = cli.harvest(p + d)
-    assert all(s == wire.E_OK for s, _ in got.values())
+    one = {"put": cli.put, "get": cli.get, "delete": cli.delete}
+    out = []
+    for ops in _KV_PHASES:
+        rids = (cli.submit(ops) if burst
+                else [one[op[0]](*op[1:]) for op in ops])
+        got = cli.harvest(rids)
+        out.append([(got[r][0], decode_record(got[r][1])[1]
+                     if op[0] == "get" and got[r][0] == wire.E_OK else None)
+                    for r, op in zip(rids, ops)])
+    return out, store
+
+
+def test_kv_client_submit_mixed_and_wrappers():
+    burst, store = _kv_ops_run(True)
+    single, _ = _kv_ops_run(False)
+    assert burst == single
+    ok, gone = wire.E_OK, wire.E_NOENT
+    assert burst == [
+        [(ok, None)] * 3,
+        [(ok, b"v1" * 8), (ok, None), (ok, b"v3"), (ok, None)],
+        # After the DEL's ack, the mapping is gone (invalidate-on-read).
+        [(gone, None), (ok, b"v4"), (ok, None)],
+        [(gone, None), (ok, b"v1" * 8)],
+    ]
     # Per-tenant stats accumulated under tenant 5 across shards.
     merged = store.latency_stats()
     assert 5 in merged["tenants"]

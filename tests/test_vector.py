@@ -320,7 +320,7 @@ def _memo_stack():
                                                segment_size=1 << 18))
     cli = KVClient(store)
     keys = [b"memo-key-%04d" % i for i in range(32)]
-    handles = cli.put_many([(k, b"v" * 32) for k in keys])
+    handles = cli.submit([("put", k, b"v" * 32) for k in keys])
     cli.harvest(handles)
     srv = store.cluster.servers[0]
     msgs = [encode_get(1000 + i, k) for i, k in enumerate(keys)]

@@ -8,7 +8,7 @@ Covers the PR-3 write pipeline end to end:
     runs, cross-segment integrity, read-your-writes barriers);
   * the file service's E_NOSPC backpressure and TailA wrap-pad slots;
   * the zero-copy write invariant (``request_copies == 0`` under a burst);
-  * ``write_many`` burst issue on both clients;
+  * write bursts through ``submit`` on both clients;
   * cache-table ``items()`` stability under cuckoo kicks and the stats
     surfaced through the KV app.
 """
@@ -18,8 +18,8 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import wire
 from repro.core.cache_table import CacheTable
-from repro.core.client import ClusterClient
-from repro.core.dds_server import (DDSClient, DDSStorageServer, ServerConfig,
+from repro.core.client import ClusterClient, DDSClient
+from repro.core.dds_server import (DDSStorageServer, ServerConfig,
                                    encode_app_write)
 from repro.core.file_service import FileServiceRunner, SegmentFS
 from repro.core.host_lib import DDSFrontEnd
@@ -324,16 +324,18 @@ def test_encode_app_write_accepts_memoryview_without_materializing():
 
 
 # ---------------------------------------------------------------------------
-# write_many burst issue
+# write bursts through submit
 # ---------------------------------------------------------------------------
 
 
-def test_dds_client_write_many_single_batch():
+def test_dds_client_submit_writes_single_batch():
     srv = DDSStorageServer(ServerConfig())
     fid = srv.frontend.create_file("wm")
     srv.run_until_idle()
     cli = DDSClient(srv)
-    rids = cli.write_many([(fid, i * 32, bytes([i]) * 32) for i in range(16)])
+    rids = cli.submit([("w", fid, i * 32, bytes([i]) * 32)
+                       for i in range(16)])
+    assert cli.stats.batches_sent == 1
     for rid in rids:
         status, _ = cli.wait(rid)
         assert status == wire.E_OK
@@ -342,15 +344,15 @@ def test_dds_client_write_many_single_batch():
     assert body == b"".join(bytes([i]) * 32 for i in range(16))
 
 
-def test_cluster_client_write_many_routes_and_coalesces():
+def test_cluster_client_submit_writes_routes_and_coalesces():
     cluster = DDSCluster(num_shards=2,
                          config=ServerConfig(device_capacity=1 << 26))
     files = [cluster.create_file(f"f{i}") for i in range(4)]
     cli = ClusterClient(cluster)
     writes = [(files[i % 4], (i // 4) * 64, bytes([i & 0xFF]) * 64)
               for i in range(32)]
-    rids = cli.write_many(writes)
-    got = cli.wait_many(rids)
+    rids = cli.submit([("w",) + w for w in writes])
+    got = cli.harvest(rids)
     assert all(status == wire.E_OK for status, _ in got.values())
     coalesced = sum(s.file_service.stats.coalesced_writes
                     for s in cluster.servers)
