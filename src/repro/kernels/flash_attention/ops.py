@@ -129,7 +129,9 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     q_offset: int | None = None, scale: float | None = None,
                     impl: str | None = None, block_q: int = 512,
                     block_k: int = 512, interpret: bool = False):
-    """GQA flash attention.  See ref.attention_ref for semantics."""
+    """GQA flash attention.  See ref.attention_ref for semantics.
+    ``block_q``/``block_k`` tile the ``xla_chunked`` path; the Pallas
+    kernel takes its tiles from the shape (``kernel.flash_plan``)."""
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "xla_chunked"
     if impl == "pallas":

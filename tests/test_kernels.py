@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.flash_attention import kernel as K
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ops import flash_attention, flash_attention_xla
 from repro.kernels.flash_attention.ref import attention_ref
@@ -32,6 +33,12 @@ FA_CASES = [
     (2, 64, 192, 4, 1, 32, False, None),
     (1, 128, 128, 6, 2, 128, True, None),
     (1, 64, 64, 2, 2, 64, True, 16),
+    # The cells' groups, scaled down in S: G = 9 at D = 128, G = 3 at D = 64.
+    (1, 160, 160, 9, 1, 128, True, None),
+    (1, 200, 200, 6, 2, 64, True, None),
+    # Queries after the keys' start (q_offset = Sk - Sq > 0), with a window.
+    (1, 96, 224, 4, 2, 64, True, None),
+    (1, 80, 208, 4, 2, 64, True, 48),
 ]
 
 
@@ -74,6 +81,105 @@ def test_flash_attention_pallas_pads_ragged_lengths(case):
     assert out.shape == ref.shape
     np.testing.assert_allclose(out, ref, atol=TOL[jnp.float32],
                                rtol=TOL[jnp.float32])
+
+
+# The tiles the wrapper picks from the shape, at the cells' groups scaled
+# down in S: several k tiles, ragged lengths, q_offset > 0, windows and
+# non-causal calls, in both dtypes.
+FA_PLANNED_CASES = [
+    # B, Sq, Sk, Hq, Hkv, D, causal, window, q_offset
+    (1, 600, 600, 9, 1, 128, True, None, 0),
+    (1, 700, 700, 6, 2, 64, True, None, 0),
+    (1, 300, 1100, 3, 1, 64, True, None, None),
+    (1, 260, 520, 9, 1, 128, True, 200, 130),
+    (1, 333, 333, 6, 2, 64, True, 100, 0),
+    (1, 800, 800, 9, 1, 128, True, 100, 0),
+    (1, 250, 610, 9, 1, 128, False, None, 0),
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", FA_PLANNED_CASES)
+def test_flash_attention_pallas_planned_tiles(case, dtype):
+    B, Sq, Sk, Hq, Hkv, D, causal, window, q_offset = case
+    ks = jax.random.split(jax.random.PRNGKey(12), 3)
+    q = _rand(ks[0], (B, Sq, Hq, D), dtype)
+    k = _rand(ks[1], (B, Sk, Hkv, D), dtype)
+    v = _rand(ks[2], (B, Sk, Hkv, D), dtype)
+    ref = attention_ref(q, k, v, causal=causal, window=window,
+                        q_offset=q_offset)
+    out = flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                 q_offset=q_offset, interpret=True)
+    assert out.shape == ref.shape
+    np.testing.assert_allclose(out.astype(jnp.float32),
+                               ref.astype(jnp.float32),
+                               atol=TOL[dtype], rtol=TOL[dtype])
+
+
+def _band(Sq, Sk, causal, window, q_offset):
+    """ref.attention_ref's mask, (Sq, Sk)."""
+    qpos = np.arange(Sq)[:, None] + q_offset
+    kpos = np.arange(Sk)[None, :]
+    keep = np.ones((Sq, Sk), bool)
+    if causal:
+        keep &= kpos <= qpos
+    if window is not None:
+        keep &= kpos > qpos - window
+    return keep
+
+
+@pytest.mark.parametrize("case", FA_PLANNED_CASES + [
+    # the cells' prefill shapes: (B, S, S, Hq, Hkv, D), causal
+    (16, 1500, 1500, 36, 4, 128, True, None, 0),
+    (16, 1020, 1020, 36, 4, 128, True, None, 0),
+    (16, 1020, 1020, 24, 8, 64, True, None, 0),
+])
+def test_flash_plan_visits_the_band_and_masks_its_edges(case):
+    """The plan visits exactly the tiles that hold a kept (query, key) pair,
+    masks exactly those that also hold a dropped pair or a padded key, and
+    lists each q block's tiles together, first and last flagged."""
+    B, Sq, Sk, Hq, Hkv, D, causal, window, q_offset = case
+    plan = K.flash_plan(B, Sq, Sk, Hkv, D, causal=causal, window=window,
+                        q_offset=q_offset)
+    assert (plan.bq, plan.bk) == K._tiles(D, Sq, Sk)
+    bq, bk = plan.bq, plan.bk
+    off = Sk - Sq if q_offset is None else q_offset
+    band = np.zeros((plan.nq * bq, plan.nk * bk), bool)
+    band[:Sq, :Sk] = _band(Sq, Sk, causal, window, off)
+    tiles = band.reshape(plan.nq, bq, plan.nk, bk)
+    # A tile is masked unless every real query row keeps every column.
+    rows_real = np.zeros((plan.nq, bq), bool)
+    rows_real.reshape(-1)[:Sq] = True
+    visited, masked = set(), set()
+    for i in range(plan.nq):
+        for j in range(plan.nk):
+            t = tiles[i, :, j, :]
+            if t.any():
+                visited.add((i, j))
+                if not t[rows_real[i]].all():
+                    masked.add((i, j))
+    got = list(zip(plan.qi.tolist(), plan.ki.tolist()))
+    assert set(got) == visited and len(got) == len(visited)
+    assert {g for g, f in zip(got, plan.flags) if f & K.MASKED} == masked
+    groups = B * Hkv
+    assert plan.visited_steps == groups * len(visited)
+    assert plan.masked_tiles == groups * len(masked)
+    assert plan.full_steps == groups * plan.nq * plan.nk
+    assert got == sorted(got)
+    for i in range(plan.nq):
+        fl = [int(f) for (a, _), f in zip(got, plan.flags) if a == i]
+        assert fl[0] & K.FIRST and fl[-1] & K.LAST
+        assert not any(f & K.FIRST for f in fl[1:])
+        assert not any(f & K.LAST for f in fl[:-1])
+
+
+def test_flash_tiles_follow_the_shape():
+    """The rule at the three cells' prefill shapes, (D, Sq, Sk) -> (bq, bk),
+    and at sequences shorter than a block."""
+    assert K._tiles(128, 1500, 1500) == (256, 256)
+    assert K._tiles(128, 1020, 1020) == (256, 256)
+    assert K._tiles(64, 1020, 1020) == (256, 512)
+    assert K._tiles(64, 100, 300) == (100, 300)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
